@@ -130,14 +130,14 @@ import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.jaxpr_tools import collective_profile
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.core import quantize
 from repro.comm.codecs import codec_for_grid
 from repro.parallel import stage_parallel as SP
 
 V, h, L, C, iters = %(V)d, %(h)d, %(L)d, 4, %(iters)d
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = ADMMConfig(nu=1e-2, rho=1.0, quantize_p=True, quantize_q=True,
                  grid=quantize.uniform_grid(8, -2.0, 6.0))
 key = jax.random.PRNGKey(0)
@@ -213,16 +213,16 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.comm import CommLedger, transport
 from repro.comm.codecs import GridCodec
 from repro.core.quantize import uniform_grid
 from repro.comm.transport import record_psum
 
 W, V, h, iters = 8, %(V)d, %(h)d, %(iters)d
-mesh = compat_make_mesh((W,), ("data",))
+mesh = make_mesh((W,), ("data",))
 codec = GridCodec(uniform_grid(4, -3.0, 3.0))
 x = jax.random.normal(jax.random.PRNGKey(0), (W * V, h))
 
@@ -230,7 +230,7 @@ def run(mode):
     def f(xx):
         return transport.quantized_psum(xx, "data", codec, mode=mode)
     sm = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("data"),),
-                           out_specs=P("data"), check_rep=False))
+                           out_specs=P("data"), check_vma=False))
     y = sm(x); jax.block_until_ready(y)         # compile + warmup
     t0 = time.perf_counter()
     for _ in range(iters):
@@ -288,7 +288,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.core import quantize
 from repro.comm import BitWidthController, CommLedger, ControllerConfig
@@ -297,7 +297,7 @@ from repro.graph.datasets import tiny
 from repro.parallel import stage_parallel as SP
 
 V, h, L, epochs = %(V)d, %(h)d, %(L)d, %(epochs)d
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 n_stages = 4
 ds = tiny(V=V)
 X = ds.augmented(4)
@@ -373,7 +373,7 @@ os.environ["REPRO_KERNELS"] = "interpret"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.core import quantize
 from repro.comm import BitWidthController, CommLedger, ControllerConfig
@@ -384,7 +384,7 @@ from repro.parallel import stage_parallel as SP
 from repro.analysis.replay import calibrate, replay
 
 V, h, L, C, iters, epochs = %(V)d, %(h)d, %(L)d, 4, %(iters)d, %(epochs)d
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 n_stages = 4
 cfg = ADMMConfig(nu=1e-2, rho=1.0, quantize_p=True, quantize_q=True,
                  grid=quantize.uniform_grid(8, -2.0, 6.0))
@@ -553,14 +553,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import shutil, tempfile
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.comm import faults as F
 from repro.comm.ledger import CommLedger
 from repro.parallel import stage_parallel as SP
 
 V, h, L, C, epochs = %(V)d, %(h)d, %(L)d, 4, %(epochs)d
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 Xp = jax.random.normal(key, (V, h))
 labels = jax.random.randint(jax.random.PRNGKey(1), (V,), 0, C)

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.analysis import hlo as H
 from repro.comm import CommLedger
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.core import quantize
 from repro.core.pdadmm import ADMMConfig
 from repro.graph.datasets import tiny
@@ -38,7 +39,8 @@ def wire_bytes(mesh, cfg, V=256, h=64, L=8, C=4):
 
 
 def main():
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    enable_compile_cache()
+    mesh = make_mesh((2, 4), ("data", "model"))
     fp = wire_bytes(mesh, ADMMConfig(nu=1e-2, rho=1.0))
     g8 = quantize.uniform_grid(8, -2.0, 6.0)
     q8 = wire_bytes(mesh, ADMMConfig(nu=1e-2, rho=1.0, quantize_p=True,

@@ -8,9 +8,11 @@ import jax
 from repro.core import pdadmm, quantize
 from repro.core.pdadmm import ADMMConfig
 from repro.graph.datasets import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ds = synthetic("cora", scale=0.5)
     X = ds.augmented(k_hops=4)         # Psi = {I, A~, A~^2, A~^3}
     dims = [X.shape[1], 100, 100, 100, ds.n_classes]

@@ -7,12 +7,14 @@ the decode dry-run cells at production scale).
 import jax
 
 from repro.configs.base import ShapeConfig, get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import build
 from repro.serve.engine import Request, ServingEngine
 
 
 def main():
+    enable_compile_cache()
     mesh = make_host_mesh()
     cfg = get_arch("tinyllama-1.1b").reduced()
     bundle = build(cfg, mesh, ShapeConfig("serve", 128, 4, "decode"))

@@ -19,9 +19,11 @@ from repro.ckpt.manager import CheckpointManager
 from repro.core import pdadmm
 from repro.core.pdadmm import ADMMConfig
 from repro.graph.datasets import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=200)
     ap.add_argument("--hidden", type=int, default=1000)

@@ -235,7 +235,7 @@ def get_spec(name: str):
 # ---------------------------------------------------------------------------
 
 def _mesh_for(shape: Tuple[int, int]):
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     need = math.prod(shape)
     devs = jax.devices()
     if len(devs) < need:
@@ -243,7 +243,7 @@ def _mesh_for(shape: Tuple[int, int]):
             f"{need}-device mesh {shape} needs XLA_FLAGS="
             f"--xla_force_host_platform_device_count>={need} "
             f"(have {len(devs)}); the lint CLI sets this up for you")
-    return compat_make_mesh(shape, ("data", "model"), devices=devs[:need])
+    return make_mesh(shape, ("data", "model"), devices=devs[:need])
 
 
 def _walk_jaxprs(jaxpr):
@@ -477,12 +477,9 @@ class PsumView:
     @property
     def jaxpr(self):
         from repro.comm.transport import quantized_psum
-        from repro.launch.mesh import compat_make_mesh
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:                      # newer jax
-            from jax.sharding import shard_map
+        from jax import shard_map
         if "jaxpr" not in self._cache:
             spec = self.spec
             devs = jax.devices()
@@ -490,13 +487,12 @@ class PsumView:
                 raise RuntimeError(
                     f"psum spec {spec.name} needs {spec.world} devices "
                     f"(have {len(devs)})")
-            m = compat_make_mesh((spec.world,), ("d",),
-                                 devices=devs[:spec.world])
+            m = make_mesh((spec.world,), ("d",), devices=devs[:spec.world])
             codec = self.codec_override or spec.codec()
             f = shard_map(lambda x: quantized_psum(x, "d", codec,
                                                    mode=self.mode),
                           mesh=m, in_specs=P("d"), out_specs=P("d"),
-                          check_rep=False)
+                          check_vma=False)
             x = jax.ShapeDtypeStruct((spec.world * spec.rows, spec.cols),
                                      jnp.float32)
             self._cache["jaxpr"] = jax.make_jaxpr(f)(x).jaxpr

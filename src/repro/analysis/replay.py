@@ -573,7 +573,7 @@ def calibrate(mesh, *, V: int = 128, h: int = 32, n_classes: int = 4,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.analysis.costs import timed
 
@@ -588,7 +588,7 @@ def calibrate(mesh, *, V: int = 128, h: int = 32, n_classes: int = 4,
 
     def smap(f):
         return jax.jit(shard_map(f, mesh=mesh, in_specs=(spec,),
-                                 out_specs=spec, check_rep=False))
+                                 out_specs=spec, check_vma=False))
 
     x = jax.device_put(
         jnp.ones((world * 4, h), jnp.float32),

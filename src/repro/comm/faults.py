@@ -89,7 +89,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.comm.codecs import WirePayload
-from repro.comm.transport import axis_size
 
 # edge order of every per-edge mask / counter in this module
 EDGES = ("q_fwd", "u_fwd", "p_bwd")
@@ -359,7 +358,7 @@ class SentinelExchange:
     plan: Optional[FaultPlan] = None
 
     def _perm(self, delta: int):
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         return [(i, (i + delta) % n) for i in range(n)]
 
     def _encode(self, slab, sel):
@@ -398,7 +397,7 @@ class SentinelExchange:
         verdict fails. Returns ``(boundary [1,V_loc,h], ok scalar bool)``."""
         payload, header = fly
         sidx = jax.lax.axis_index(self.axis_name)
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         src = jnp.mod(sidx - delta, n)
         if self.plan is not None:
             k = jax.random.fold_in(jax.random.fold_in(ctl.key, self.edge),

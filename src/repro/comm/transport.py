@@ -60,7 +60,6 @@ same :func:`~repro.comm.faults.payload_checksum` verifies packed
 from __future__ import annotations
 
 import dataclasses
-import operator
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -70,27 +69,6 @@ from repro.comm.codecs import (FP32, AffineCodec, Fp32Codec, GridCodec,
                                WireCodec, WirePayload, _body_bytes,
                                _container_dtype, _n_elements)
 from repro.kernels import ops
-
-
-def axis_size(axis_name: str):
-    """`jax.lax.axis_size` compat. Older JAX exposes the size via
-    ``jax.core.axis_frame``, which returns the static int on some 0.4.x
-    releases and a frame OBJECT (with a ``.size`` attribute) on others —
-    normalize both to a plain Python int and refuse anything else loudly
-    (``operator.index`` raises TypeError on a non-integral frame)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        frame = jax.core.axis_frame(axis_name)
-        try:
-            return operator.index(frame)        # already an integral size
-        except TypeError:
-            size = getattr(frame, "size", None)
-            if size is None:
-                raise TypeError(
-                    f"axis_frame({axis_name!r}) returned {frame!r}; "
-                    "expected an integral size or a frame with `.size`")
-            return operator.index(size)
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +110,7 @@ class NeighborExchange:
         """Encode x_loc[-1:] and issue the forward boundary ppermute; the
         returned in-flight payload is consumed by `finish_shift_from_prev`
         (possibly next iteration, with the same x_loc values)."""
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         perm = [(i, (i + 1) % n) for i in range(n)]
         return self._start(x_loc[-1:], perm)
 
@@ -153,7 +131,7 @@ class NeighborExchange:
     # -- backward shift (out[i] = x[i+1]) -----------------------------------
     def start_shift_from_next(self, x_loc) -> WirePayload:
         """Encode x_loc[:1] and issue the backward boundary ppermute."""
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         perm = [(i, (i - 1) % n) for i in range(n)]
         return self._start(x_loc[:1], perm)
 
@@ -280,7 +258,7 @@ def quantized_psum(x, axis_name: str, codec: WireCodec = AffineCodec(8), *,
     """
     if _check_mode(mode) == "psum" or isinstance(codec, Fp32Codec):
         return jax.lax.psum(x, axis_name)
-    w = axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     if mode is None:
         mode = psum_mode(codec, w)
     codes, zero, scale = _shared_codes(x, axis_name, codec, key)
@@ -306,7 +284,7 @@ def psum_with_error_feedback(x, err, axis_name: str,
     target = x + err
     if _check_mode(mode) == "psum" or isinstance(codec, Fp32Codec):
         return jax.lax.psum(target, axis_name), jnp.zeros_like(target)
-    w = axis_size(axis_name)
+    w = jax.lax.axis_size(axis_name)
     if mode is None:
         mode = psum_mode(codec, w)
     codes, zero, scale = _shared_codes(target, axis_name, codec, key)
@@ -442,7 +420,7 @@ class ContainerExchange:
     wire: PaddedWire
 
     def _perm(self, delta: int):
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         return [(i, (i + delta) % n) for i in range(n)]
 
     # -- forward shift (out[i] = x[i-1]) ------------------------------------

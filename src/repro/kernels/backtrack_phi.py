@@ -9,11 +9,14 @@ Each trial of the projected backtracking search needs the data-fit term of
 The naive evaluation materializes the [V, n_out] product, writes it to HBM,
 re-reads it to subtract from r0, and re-reads the difference to reduce. Here
 the d@W tiles accumulate in VMEM, the subtraction and squared reduction ride
-the final K step, and only one f32 partial per (m, n) tile ever touches HBM
-— the trial's HBM traffic drops from O(V·n_out) to O(V·n_out / (bm·bn)).
+the final K step, and only one f32 lane row of column partials per (m, n)
+tile ever touches HBM — the trial's HBM traffic drops from O(V·n_out) to
+O(V·n_out / bm).
 
-The host-side sum of the per-tile partials is a [n_m, n_n] reduction — noise
-next to the contraction itself.
+Each tile reduces over its rows only and writes a (1, bn) row into a
+[M/bm, 1, N] partials array (a block shape the TPU layout rules accept,
+unlike a (1, 1) scalar block); the final ``jnp.sum`` over the partials is
+noise next to the contraction itself.
 """
 from __future__ import annotations
 
@@ -38,7 +41,7 @@ def _resnorm_kernel(r0_ref, d_ref, w_ref, out_ref, acc_ref, *, n_k: int):
     @pl.when(k == n_k - 1)
     def _reduce():
         r = r0_ref[...].astype(jnp.float32) - acc_ref[...]
-        out_ref[0, 0] = jnp.sum(r * r)
+        out_ref[...] = jnp.sum(r * r, axis=0, keepdims=True)
 
 
 def backtrack_resnorm(r0, d, W, *, bm: int = 256, bk: int = 512,
@@ -61,8 +64,8 @@ def backtrack_resnorm(r0, d, W, *, bm: int = 256, bk: int = 512,
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),   # d
             pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),   # W
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda m, n, k: (m, n)),
-        out_shape=jax.ShapeDtypeStruct((M // bm, N // bn), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, bn), lambda m, n, k: (m, 0, n)),
+        out_shape=jax.ShapeDtypeStruct((M // bm, 1, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(r0, d, W)

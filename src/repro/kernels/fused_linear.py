@@ -4,7 +4,8 @@ the intermediate never round-trips HBM.
 
 Tiling: grid (M/bm, N/bn, K/bk), K innermost; f32 accumulator lives in a VMEM
 scratch tile that is revisited across the K steps (standard MXU pattern,
-128-aligned tiles).
+128-aligned tiles). The bias rides as a (1, N) row so its (1, bn) block obeys
+the TPU's 2-D block layout rules.
 """
 from __future__ import annotations
 
@@ -57,11 +58,11 @@ def fused_linear(p, W, b, z=None, *, mode: str = "linear",
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),
             pl.BlockSpec((bk, bn), lambda m, n, k: (k, n)),
-            pl.BlockSpec((bn,), lambda m, n, k: (n,)),
+            pl.BlockSpec((1, bn), lambda m, n, k: (0, n)),
             pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), p.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(p, W, b, z)
+    )(p, W, b.reshape(1, N), z)

@@ -48,7 +48,8 @@ int16; the layout contract is ``comm.codecs.pack_codes_jnp``). They are the
 fused half of the gather-based packed all-reduce and the padded-container
 boundary exchange in ``comm/transport.py`` — the former "packed-int4 psum"
 kernel gap. Packing is elementwise, so there is no tile-divisibility guard:
-ragged streams take the single-block fallback.
+ragged streams (like ragged ``relu_zupdate`` shapes) take a ``pl.cdiv`` grid
+whose edge block Pallas masks — no padding copy.
 """
 from __future__ import annotations
 
@@ -78,8 +79,6 @@ KERNEL_NAMES = {
     "relu_zupdate": "_zupdate_kernel",
     "flash_attention": "_flash_kernel",
     "grid_project": "_project_kernel",
-    "grid_encode": "_encode_kernel",
-    "grid_decode": "_decode_kernel",
 }
 
 
@@ -295,21 +294,6 @@ def grid_project(x, grid, *, use_pallas=None, interpret=None):
     return _qk.grid_project(x, grid, interpret=it)
 
 
-def grid_encode(x, grid, *, use_pallas=None, interpret=None):
-    up, it = _resolve(use_pallas, interpret)
-    if not up:
-        return ref.grid_encode_ref(x, grid)
-    return _qk.grid_encode(x, grid, interpret=it)
-
-
-def grid_decode(codes, grid, out_dtype=jnp.float32, *, use_pallas=None,
-                interpret=None):
-    up, it = _resolve(use_pallas, interpret)
-    if not up:
-        return ref.grid_decode_ref(codes, grid, out_dtype)
-    return _qk.grid_decode(codes, grid, out_dtype, interpret=it)
-
-
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _relu_zupdate(a, q, z_old, *, use_pallas, interpret):
     if not use_pallas:
@@ -319,14 +303,10 @@ def _relu_zupdate(a, q, z_old, *, use_pallas, interpret):
 
 def relu_zupdate(a, q, z_old, *, use_pallas=None, interpret=None):
     """Fused Eq.-6 ReLU z-update. Accepts [..., V, n]: leading axes (the
-    layer-stacked fast path) are flattened into the row dimension — the op
-    is elementwise, so the tiling is shape-free."""
+    layer-stacked fast path) ride a grid axis of their own — the op is
+    elementwise, so the tiling is shape-free."""
     up, it = _resolve(use_pallas, interpret)
-    shape = a.shape
-    if a.ndim > 2:
-        a, q, z_old = (t.reshape(-1, shape[-1]) for t in (a, q, z_old))
-    out = _relu_zupdate(a, q, z_old, use_pallas=up, interpret=it)
-    return out.reshape(shape)
+    return _relu_zupdate(a, q, z_old, use_pallas=up, interpret=it)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "use_pallas",

@@ -18,9 +18,8 @@ Layout contract (shared bit-for-bit with the jnp oracle
 All in-kernel arithmetic runs in int32 (TPU shift semantics on sub-32-bit
 integers are not guaranteed across generations) and casts to the container
 dtype on the way out. The public helpers view the flat stream as one
-``(1, m)`` row — the ops are elementwise, so the tiling is shape-free, with
-the single-block fallback for ragged lengths exactly like
-``quantize_kernel``.
+``(1, m)`` row — the ops are elementwise, so the tiling is shape-free: a
+ragged length takes a ``pl.cdiv`` grid whose edge block Pallas masks.
 """
 from __future__ import annotations
 
@@ -56,16 +55,15 @@ def _unpack16_kernel(hi_ref, lo_ref, o_ref):
 def _rowcall(kernel, ins, out_dtypes, *, bn: int = 8192,
              interpret: bool = False):
     """Run an elementwise multi-in/multi-out kernel over flat streams viewed
-    as one (1, m) row, tiled (1, bn) with the single-block ragged fallback."""
+    as one (1, m) row, tiled (1, bn); the edge block of a ragged length hangs
+    past the row and its out-of-bounds writes are masked."""
     m = ins[0].shape[0]
     if m == 0:                         # nothing to move; match the oracle
         return [jnp.zeros((0,), dt) for dt in out_dtypes]
     bn_ = min(bn, m)
-    if m % bn_:
-        bn_ = m
     outs = pl.pallas_call(
         kernel,
-        grid=(m // bn_,),
+        grid=(pl.cdiv(m, bn_),),
         in_specs=[pl.BlockSpec((1, bn_), lambda i: (0, i))] * len(ins),
         out_specs=[pl.BlockSpec((1, bn_), lambda i: (0, i))] * len(out_dtypes),
         out_shape=[jax.ShapeDtypeStruct((1, m), dt) for dt in out_dtypes],

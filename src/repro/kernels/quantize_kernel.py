@@ -1,8 +1,7 @@
-"""Pallas TPU kernel: fused grid-projection / wire-encode for pdADMM-G-Q.
+"""Pallas TPU kernel: fused grid projection for pdADMM-G-Q.
 
-Elementwise, VPU-bound — the value of the kernel is fusing
-project+encode (resp. decode) into ONE pass over the tensor right at the
-collective boundary, halving the HBM reads the quantized exchange costs.
+Elementwise, VPU-bound — the value of the kernel is fusing the scale,
+round, clip and rescale of the projection into ONE pass over the tensor.
 """
 from __future__ import annotations
 
@@ -19,48 +18,20 @@ def _project_kernel(x_ref, o_ref, *, lo, step, n_levels):
     o_ref[...] = (lo + ix * step).astype(o_ref.dtype)
 
 
-def _encode_kernel(x_ref, o_ref, *, lo, step, n_levels):
-    x = x_ref[...].astype(jnp.float32)
-    ix = jnp.clip(jnp.round((x - lo) / step), 0, n_levels - 1)
-    o_ref[...] = ix.astype(o_ref.dtype)
-
-
-def _decode_kernel(c_ref, o_ref, *, lo, step):
-    o_ref[...] = (lo + c_ref[...].astype(jnp.float32) * step).astype(o_ref.dtype)
-
-
-def _elementwise_call(kernel, x, out_dtype, *, bm: int = 512, bn: int = 1024,
-                      interpret: bool = False):
+def grid_project(x, grid, *, bm: int = 512, bn: int = 1024,
+                 interpret: bool = False):
+    kernel = functools.partial(_project_kernel, lo=grid.lo, step=grid.step,
+                               n_levels=grid.n_levels)
     orig_shape = x.shape
     x2 = x.reshape(-1, orig_shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
     M, N = x2.shape
     bm_, bn_ = min(bm, M), min(bn, N)
-    if M % bm_ or N % bn_:
-        bm_, bn_ = M, N      # fallback: single block for ragged shapes
     out = pl.pallas_call(
         kernel,
-        grid=(M // bm_, N // bn_),
+        grid=(pl.cdiv(M, bm_), pl.cdiv(N, bn_)),     # ragged edge: masked
         in_specs=[pl.BlockSpec((bm_, bn_), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
     )(x2)
     return out.reshape(orig_shape)
-
-
-def grid_project(x, grid, *, interpret: bool = False):
-    k = functools.partial(_project_kernel, lo=grid.lo, step=grid.step,
-                          n_levels=grid.n_levels)
-    return _elementwise_call(k, x, x.dtype, interpret=interpret)
-
-
-def grid_encode(x, grid, *, interpret: bool = False):
-    dtype = jnp.uint8 if grid.bits <= 8 else jnp.uint16
-    k = functools.partial(_encode_kernel, lo=grid.lo, step=grid.step,
-                          n_levels=grid.n_levels)
-    return _elementwise_call(k, x, dtype, interpret=interpret)
-
-
-def grid_decode(codes, grid, out_dtype=jnp.float32, *, interpret: bool = False):
-    k = functools.partial(_decode_kernel, lo=grid.lo, step=grid.step)
-    return _elementwise_call(k, codes, out_dtype, interpret=interpret)

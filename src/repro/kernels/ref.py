@@ -30,14 +30,6 @@ def grid_project_ref(x, grid):
     return grid.project(x)
 
 
-def grid_encode_ref(x, grid):
-    return grid.encode(x)
-
-
-def grid_decode_ref(codes, grid, out_dtype=jnp.float32):
-    return grid.decode(codes, out_dtype)
-
-
 def fista_zlast_ref(a, z_old, labels, label_mask, *, nu: float,
                     n_iters: int = 15, n_classes=None):
     """jnp oracle for the fused FISTA z_L kernel: the shared

@@ -25,17 +25,26 @@ def _zupdate_kernel(a_ref, q_ref, zold_ref, o_ref):
     o_ref[...] = jnp.where(obj(zn) <= obj(zp), zn, zp).astype(o_ref.dtype)
 
 
-def relu_zupdate(a, q, z_old, *, bm: int = 512, bn: int = 1024,
+ROW_TILE = 256      # rows per block; 512 overflows v5e's 16 MiB scoped VMEM
+
+
+def relu_zupdate(a, q, z_old, *, bm: int = ROW_TILE, bn: int = 1024,
                  interpret: bool = False):
-    M, N = a.shape
+    """a, q, z_old: [..., M, N]. Leading axes (the layer stack) become one
+    grid axis, so a ragged M never forces a relayout copy of the stack.
+    Ragged M/N take a ``pl.cdiv`` grid: the edge blocks hang past the array
+    and Pallas masks their out-of-bounds writes, which is exact for an
+    elementwise op (no padding copy, and VMEM stays one tile deep)."""
+    shape = a.shape
+    M, N = shape[-2:]
+    a, q, z_old = (t.reshape(-1, M, N) for t in (a, q, z_old))
     bm_, bn_ = min(bm, M), min(bn, N)
-    if M % bm_ or N % bn_:
-        bm_, bn_ = M, N
+    spec = pl.BlockSpec((None, bm_, bn_), lambda l, i, j: (l, i, j))
     return pl.pallas_call(
         _zupdate_kernel,
-        grid=(M // bm_, N // bn_),
-        in_specs=[pl.BlockSpec((bm_, bn_), lambda i, j: (i, j))] * 3,
-        out_specs=pl.BlockSpec((bm_, bn_), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
+        grid=(a.shape[0], pl.cdiv(M, bm_), pl.cdiv(N, bn_)),
+        in_specs=[spec] * 3,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         interpret=interpret,
-    )(a, q, z_old)
+    )(a, q, z_old).reshape(shape)

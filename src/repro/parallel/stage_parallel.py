@@ -25,7 +25,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comm import faults as FT
@@ -486,13 +486,13 @@ def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
             wrapped, mesh=mesh,
             in_specs=(carry_specs, P(dp), P(dp), P(dp)) + extra_specs,
             out_specs=(carry_specs, P()),
-            check_rep=False)
+            check_vma=False)
     else:
         smapped = shard_map(
             lambda c, Xp, lab, msk: stage_body(c, Xp, lab, msk), mesh=mesh,
             in_specs=(carry_specs, P(dp), P(dp), P(dp)),
             out_specs=(carry_specs, P()),
-            check_rep=False)
+            check_vma=False)
 
     return jax.jit(smapped, donate_argnums=(0,) if donate else ()), stack_specs
 
@@ -541,13 +541,13 @@ def make_overlap_primer(mesh: Mesh, q_codec: WireCodec = FP32, *,
                 in_specs=(P("model", dp), P("model", dp), P(), P()),
                 out_specs=((_payload_spec(wire, dp), hdr_spec),
                            (_payload_spec(FP32, dp), hdr_spec)),
-                check_rep=False))
+                check_vma=False))
         return jax.jit(shard_map(
             prime_s, mesh=mesh,
             in_specs=(P("model", dp), P("model", dp), P()),
             out_specs=((_payload_spec(q_codec, dp), hdr_spec),
                        (_payload_spec(FP32, dp), hdr_spec)),
-            check_rep=False))
+            check_vma=False))
 
     def prime(q, u):
         return (ex_q.start_shift_from_prev(q), ex_u.start_shift_from_prev(u))
@@ -562,12 +562,12 @@ def make_overlap_primer(mesh: Mesh, q_codec: WireCodec = FP32, *,
             prime_container, mesh=mesh,
             in_specs=(P("model", dp), P("model", dp), P()),
             out_specs=(_payload_spec(wire, dp), _payload_spec(FP32, dp)),
-            check_rep=False))
+            check_vma=False))
     return jax.jit(shard_map(
         prime, mesh=mesh,
         in_specs=(P("model", dp), P("model", dp)),
         out_specs=(_payload_spec(q_codec, dp), _payload_spec(FP32, dp)),
-        check_rep=False))
+        check_vma=False))
 
 
 def make_sentinel_primer(mesh: Mesh, p_codec: WireCodec = FP32,
@@ -608,11 +608,11 @@ def make_sentinel_primer(mesh: Mesh, p_codec: WireCodec = FP32,
         return jax.jit(shard_map(
             prime_container, mesh=mesh,
             in_specs=(P("model", dp),) * 3 + (P(),),
-            out_specs=gspec, check_rep=False))
+            out_specs=gspec, check_vma=False))
     return jax.jit(shard_map(
         prime, mesh=mesh,
         in_specs=(P("model", dp),) * 3,
-        out_specs=gspec, check_rep=False))
+        out_specs=gspec, check_vma=False))
 
 
 def shard_rows(V: int, dp_total: int) -> tuple:

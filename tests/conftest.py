@@ -6,23 +6,12 @@ scheduling helpers `jaxprs_with`/`collective_profile` that the overlap
 battery uses to prove ppermutes moved off the critical path) from its
 library home `repro.analysis.jaxpr_tools` — the walkers graduated from
 test-only code when the replay cost model started building its task DAG
-from the same jaxpr walks. Also provides a minimal, deterministic stand-in
-for `hypothesis` when the real package is not installed (the pinned
-CI/container image ships without it).
-The shim implements exactly the API surface these tests use — ``given``,
-``settings`` and the ``floats/integers/lists/sampled_from/composite``
-strategies — drawing a fixed number of pseudo-random examples from a
-per-test seeded RNG, with endpoint bias so boundary values are always
-exercised. When `hypothesis` IS available it is used untouched.
+from the same jaxpr walks.
 """
 from __future__ import annotations
 
-import hashlib
 import os
 import sys
-import types
-
-import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
@@ -30,112 +19,3 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 from repro.analysis.jaxpr_tools import (collective_profile,  # noqa: F401,E402
                                         count_primitive, count_primitives,
                                         jaxprs_with)
-
-
-try:  # pragma: no cover - prefer the real thing when present
-    import hypothesis  # noqa: F401
-except ImportError:
-    _DEFAULT_MAX_EXAMPLES = 20
-
-    class _Strategy:
-        def __init__(self, sample):
-            self._sample = sample
-
-        def sample(self, rng):
-            return self._sample(rng)
-
-    def _floats(min_value=-1e9, max_value=1e9, allow_nan=True, width=64,
-                **_kw):
-        lo, hi = float(min_value), float(max_value)
-
-        def sample(rng):
-            r = rng.random()
-            if r < 0.05:
-                return lo
-            if r < 0.10:
-                return hi
-            x = lo + (hi - lo) * rng.random()
-            return float(np.float32(x)) if width == 32 else x
-
-        return _Strategy(sample)
-
-    def _integers(min_value, max_value):
-        def sample(rng):
-            r = rng.random()
-            if r < 0.05:
-                return int(min_value)
-            if r < 0.10:
-                return int(max_value)
-            return int(rng.integers(min_value, max_value + 1))
-
-        return _Strategy(sample)
-
-    def _lists(elements, min_size=0, max_size=10, **_kw):
-        def sample(rng):
-            n = int(rng.integers(min_size, max_size + 1))
-            return [elements.sample(rng) for _ in range(n)]
-
-        return _Strategy(sample)
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[int(rng.integers(len(seq)))])
-
-    def _composite(fn):
-        def make(*args, **kwargs):
-            def sample(rng):
-                return fn(lambda s: s.sample(rng), *args, **kwargs)
-
-            return _Strategy(sample)
-
-        return make
-
-    def given(*strategies):
-        def deco(fn):
-            inner = fn
-
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_max_examples",
-                            getattr(inner, "_max_examples",
-                                    _DEFAULT_MAX_EXAMPLES))
-                seed = int.from_bytes(
-                    hashlib.sha256(inner.__name__.encode()).digest()[:4],
-                    "big")
-                rng = np.random.default_rng(seed)
-                for i in range(n):
-                    drawn = [s.sample(rng) for s in strategies]
-                    try:
-                        inner(*args, *drawn, **kwargs)
-                    except Exception as e:  # noqa: BLE001 - re-raise w/ repro
-                        raise AssertionError(
-                            f"falsifying example #{i}: {drawn!r}") from e
-
-            wrapper.__name__ = inner.__name__
-            wrapper.__doc__ = inner.__doc__
-            wrapper.__module__ = inner.__module__
-            return wrapper
-
-        return deco
-
-    def settings(max_examples=_DEFAULT_MAX_EXAMPLES, **_kw):
-        def deco(fn):
-            fn._max_examples = max_examples
-            return fn
-
-        return deco
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.floats = _floats
-    _st.integers = _integers
-    _st.lists = _lists
-    _st.sampled_from = _sampled_from
-    _st.composite = _composite
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = given
-    _hyp.settings = settings
-    _hyp.strategies = _st
-    _hyp.__is_repro_shim__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

@@ -298,54 +298,24 @@ def test_train_adaptive_managed_u_beats_fixed8_savings():
     assert hist["test_acc"][-1] > 0.5
 
 
-# --- axis_size compat fallback ----------------------------------------------
-
-
-def test_axis_size_fallback_normalizes_frames(monkeypatch):
-    """`jax.core.axis_frame` returns a plain int on some 0.4.x releases and
-    a frame OBJECT (with `.size`) on others — the compat shim must hand back
-    a real int either way, and refuse non-integral frames loudly."""
-    import jax as _jax
-
-    from repro.comm import transport
-    if hasattr(_jax.lax, "axis_size"):
-        pytest.skip("jax.lax.axis_size exists; the fallback path is unused")
-    # int-returning axis_frame (the pinned 0.4.37 behavior)
-    monkeypatch.setattr(_jax.core, "axis_frame", lambda name: 4)
-    n = transport.axis_size("model")
-    assert n == 4 and type(n) is int
-    # frame-object variants normalize through `.size`
-    frame = type("Frame", (), {"size": 7})()
-    monkeypatch.setattr(_jax.core, "axis_frame", lambda name: frame)
-    assert transport.axis_size("model") == 7
-    # numpy integral sizes collapse to a plain int
-    monkeypatch.setattr(_jax.core, "axis_frame", lambda name: np.int64(3))
-    n = transport.axis_size("model")
-    assert n == 3 and type(n) is int
-    # anything non-integral is a loud TypeError, not a silent bad size
-    monkeypatch.setattr(_jax.core, "axis_frame",
-                        lambda name: type("Odd", (), {})())
-    with pytest.raises(TypeError):
-        transport.axis_size("model")
+# --- axis size inside shard_map -------------------------------------------
 
 
 def test_axis_size_inside_shard_map():
-    """On the pinned jax the fallback is the LIVE path: axis_size must
-    return the static int under a shard_map trace (NeighborExchange builds
-    its ppermute ring from it)."""
+    """`jax.lax.axis_size` must return the static int under a shard_map
+    trace (NeighborExchange builds its ppermute ring from it)."""
     out = _run(PRELUDE + """
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.comm.transport import axis_size
 
 sizes = []
 def f(x):
-    n = axis_size("model")
+    n = jax.lax.axis_size("model")
     assert type(n) is int, type(n)
     sizes.append(n)
     return x * n
 sm = shard_map(f, mesh=mesh, in_specs=(P("model"),), out_specs=P("model"),
-               check_rep=False)
+               check_vma=False)
 y = sm(jnp.ones((8, 2)))
 assert sizes and all(n == 4 for n in sizes), sizes
 assert np.allclose(np.asarray(y), 4.0)
@@ -368,8 +338,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 """
 
 
@@ -378,7 +348,7 @@ def test_transport_psum_and_error_feedback_unbiased():
     and the error-feedback variant keeps `quantized_psum` unbiased over
     repeated calls (drift bounded by a single round's error)."""
     out = _run(PRELUDE + """
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.comm.codecs import AffineCodec, GridCodec
 from repro.comm import transport
@@ -391,7 +361,7 @@ def f(x, e):
     return s, s2, ne
 
 sm = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-               out_specs=(P("data"), P("data"), P("data")), check_rep=False)
+               out_specs=(P("data"), P("data"), P("data")), check_vma=False)
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 32))
 exact = x.reshape(2, 4, 32).sum(0)
 e = jnp.zeros_like(x)
@@ -413,7 +383,7 @@ def test_neighbor_exchange_int4_wire():
     """int4 nibble-packed boundary exchange round-trips through ppermute
     (payload physically half the int8 size) and matches the ring shift."""
     out = _run(PRELUDE + """
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.comm.codecs import GridCodec
 from repro.comm.transport import NeighborExchange
@@ -424,7 +394,7 @@ ex = NeighborExchange("model", GridCodec(grid))
 def f(x):
     return ex.shift_from_prev(x)
 sm = shard_map(f, mesh=mesh, in_specs=(P("model"),), out_specs=P("model"),
-               check_rep=False)
+               check_vma=False)
 x = jax.random.uniform(jax.random.PRNGKey(0), (8, 16, 4))
 out = sm(x)
 # global semantics: out[i] = project(x[i-1]) at stage boundaries (stage size

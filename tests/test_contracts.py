@@ -190,9 +190,8 @@ def test_mutation_dtype_f64_leak():
     the silent-promotion bug the contract guards against."""
     out = _run(PRELUDE + """
 import jax, jax.numpy as jnp
-from jax.experimental import enable_x64
 view = CT.ProgramView(CT.get_spec("baseline"))
-with enable_x64():
+with jax.enable_x64():
     closed = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
         jax.ShapeDtypeStruct((4, 4), jnp.float32))
 view._cache["traced"] = (None, None, (), closed)

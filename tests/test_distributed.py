@@ -21,8 +21,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 """
 
 
@@ -75,6 +75,47 @@ print("LEMMA4_DIST_OK")
     assert "LEMMA4_DIST_OK" in out
 
 
+def test_stage_mesh_matches_one_device_f64():
+    """Splitting the layers over four stages leaves pdADMM's iteration map
+    unchanged: in float64, a (1, 4) mesh and a (1, 1) mesh reach the same
+    state, leaf by leaf, with the fp32 and the grid-coded wire."""
+    out = _run(PRELUDE + """
+jax.config.update("jax_enable_x64", True)
+from repro.configs.gamlp_paper import GAMLP
+from repro.core.pdadmm import ADMMConfig
+from repro.core.quantize import integer_grid
+from repro.graph.datasets import synthetic
+from repro.parallel import stage_parallel as SP
+ds = synthetic("pubmed", seed=0, scale=0.02)          # V = 394, ragged
+X = ds.augmented(4)
+key = jax.random.PRNGKey(0)
+P0 = jax.random.normal(key, (X.shape[1], 64)) * jnp.sqrt(2.0 / X.shape[1])
+Xp = jnp.maximum(X @ P0, 0).astype(jnp.float64)
+base = dict(nu=GAMLP.nu, rho=GAMLP.rho, fista_iters=GAMLP.fista_iters)
+grid = integer_grid(min(GAMLP.quant_levels), max(GAMLP.quant_levels))
+devs = jax.devices()
+meshes = [make_mesh(s, ("data", "model"), devices=devs[:s[1]])
+          for s in ((1, 4), (1, 1))]
+for cfg in (ADMMConfig(**base),
+            ADMMConfig(**base, quantize_p=GAMLP.quantize_p,
+                       quantize_q=GAMLP.quantize_q, grid=grid)):
+    finals = []
+    for m in meshes:
+        st = jax.tree.map(lambda a: a.astype(jnp.float64),
+                          SP.init_stack(key, Xp, 8, cfg))
+        step, _ = SP.make_distributed_step(m, 8, ds.n_classes, cfg)
+        for _ in range(6):
+            st, _ = step(st, Xp, ds.labels, ds.masks["train"])
+        finals.append(jax.device_get(st))
+    for name, a, b in zip(finals[1]._fields, *finals):
+        assert a.dtype == np.float64, name
+        gap = np.linalg.norm(a - b)
+        assert gap <= 1e-12 * np.linalg.norm(b), (name, gap)
+print("MESH_F64_OK")
+""")
+    assert "MESH_F64_OK" in out
+
+
 def test_quantized_wire_reduces_ppermute_bytes():
     """HLO proof of the paper's claim: int8 wire shrinks collective-permute
     payloads 4x vs fp32."""
@@ -111,7 +152,7 @@ print("WIRE_OK")
 def test_quantized_psum_error_feedback():
     out = _run(PRELUDE + """
 from repro.parallel.collectives import psum_with_error_feedback
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 def f(x, e):
@@ -119,7 +160,7 @@ def f(x, e):
     return s, ne
 
 sm = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-               out_specs=(P("data"), P("data")), check_rep=False)
+               out_specs=(P("data"), P("data")), check_vma=False)
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 32))
 e = jnp.zeros_like(x)
 s, ne = sm(x, e)

@@ -17,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.launch.mesh as M
 
 # shrink the production mesh for the test
-M.make_production_mesh = lambda multi_pod=False: M._mk(
+M.make_production_mesh = lambda multi_pod=False: M.make_mesh(
     (2, 2, 2) if multi_pod else (2, 4),
     ("pod", "data", "model") if multi_pod else ("data", "model"))
 

@@ -43,12 +43,12 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.parallel import stage_parallel as SP
 from repro.comm import faults as F
 from repro.comm.ledger import CommLedger
-mesh = compat_make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 n_stages, dp_total = 2, 2
 V, h, L, C = 32, 8, 4, 3
 key = jax.random.PRNGKey(0)
@@ -445,8 +445,8 @@ assert 0 < len(h2["objective"]) < 14     # resumed mid-flight
 assert np.isfinite(h2["objective"]).all()
 assert h2["objective"][-1] <= h2["objective"][0]       # still descending
 # ELASTIC: restore the same checkpoint onto a (1, 4) mesh
-mesh2 = compat_make_mesh((1, 4), ("data", "model"),
-                         devices=jax.devices()[:4])
+mesh2 = make_mesh((1, 4), ("data", "model"),
+                  devices=jax.devices()[:4])
 _, h3 = SP.distributed_train(mesh2, key, Xp, labels, masks, L, C, cfg, 14,
                              ckpt=d, ckpt_every=0, resume=True)
 assert np.isfinite(h3["objective"]).all()

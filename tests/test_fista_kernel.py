@@ -192,9 +192,10 @@ def test_momentum_schedule_matches_fori_loop_t_sequence():
 # --- seeded end-to-end convergence golden -----------------------------------
 
 GOLDEN_CITESEER = {
-    # recorded from the seeded run below (REPRO_KERNELS=ref, jax 0.4.37 CPU);
-    # both dispatch families must land on this trajectory
-    "final_objective": 5.2706110e-3,
+    # recorded from the seeded run below (REPRO_KERNELS=ref, jax 0.9.0 CPU,
+    # whose default jax_threefry_partitionable=True draws a different init
+    # than 0.4.x did); both dispatch families must land on this trajectory
+    "final_objective": 4.4958619e-3,
     "rtol": 2e-3,
 }
 

@@ -12,7 +12,7 @@ from repro.kernels.admm_pgrad import admm_pgrad
 from repro.kernels.backtrack_phi import backtrack_resnorm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_linear import fused_linear
-from repro.kernels.quantize_kernel import grid_decode, grid_encode, grid_project
+from repro.kernels.quantize_kernel import grid_project
 from repro.kernels.relu_zupdate import relu_zupdate
 
 
@@ -85,17 +85,10 @@ def test_quantize_kernels(shape, grid):
 
     assert_tie_tolerant(grid_project(x, grid, interpret=True),
                         ref.grid_project_ref(x, grid), grid.step)
-    enc = grid_encode(x, grid, interpret=True)
-    assert_tie_tolerant(enc, ref.grid_encode_ref(x, grid), 1)
-    dec = grid_decode(enc, grid, interpret=True)
-    np.testing.assert_allclose(np.asarray(dec),
-                               np.asarray(ref.grid_decode_ref(enc, grid)),
-                               atol=1e-6)
-    # roundtrip == projection (same tie tolerance)
-    assert_tie_tolerant(dec, grid.project(x), grid.step)
 
 
-@pytest.mark.parametrize("shape", [(256, 512), (128, 100), (512, 1000)])
+@pytest.mark.parametrize("shape", [(256, 512), (128, 100), (512, 1000),
+                                   (3, 300, 1100)])
 def test_relu_zupdate(shape):
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     a, q, z0 = (jax.random.normal(k, shape) for k in ks)

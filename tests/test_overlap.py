@@ -28,12 +28,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.core import quantize
 from repro.parallel import stage_parallel as SP
 # the paper's 2-stage x 2-data differential mesh
-mesh = compat_make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 """
 
 

@@ -39,7 +39,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -50,7 +50,7 @@ POLICIES = [{"use_pallas": False},                      # jnp oracle
 
 
 @pytest.mark.parametrize("bits", [4, 8, 16])
-@pytest.mark.parametrize("n", [0, 1, 2, 7, 17, 128, 1000, 2485, 3327])
+@pytest.mark.parametrize("n", [0, 1, 2, 7, 17, 128, 1000, 2485, 3327, 20001])
 def test_pack_unpack_roundtrip_all_policies(bits, n):
     """Roundtrip identity + exact container size for every width, odd and
     ragged element counts, on both the jnp oracle and the Pallas kernel —
@@ -178,15 +178,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_ENABLE_X64"] = "1"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.comm import transport
 from repro.comm.codecs import AffineCodec, GridCodec
 from repro.core.quantize import uniform_grid
 
 for w in (2, 4, 8):
-    mesh = compat_make_mesh((w,), ("data",), devices=jax.devices()[:w])
+    mesh = make_mesh((w,), ("data",), devices=jax.devices()[:w])
     for codec in (GridCodec(uniform_grid(4, -3.0, 3.0)),
                   GridCodec(uniform_grid(8, -3.0, 3.0)),
                   AffineCodec(8), AffineCodec(16)):
@@ -196,7 +196,7 @@ for w in (2, 4, 8):
                     transport.quantized_psum(x, "data", codec,
                                              mode="code_psum"))
         sm = shard_map(f, mesh=mesh, in_specs=(P("data"),),
-                       out_specs=(P("data"), P("data")), check_rep=False)
+                       out_specs=(P("data"), P("data")), check_vma=False)
         x = jax.random.normal(jax.random.PRNGKey(0), (w * 3, 17),
                               jnp.float64)
         a, b = sm(x)
@@ -217,16 +217,15 @@ def test_error_feedback_unbiased_on_gather_path_1k_rounds():
     """Satellite bugfix lock: `psum_with_error_feedback` computes its
     residual against the DECODED PACKED payload, so 1000 stochastic rounds
     on the gather path keep the cumulative mean within one round's
-    quantization error of the exact psum — for several seeds (pinned jax:
-    plain parametrized seeds, no hypothesis)."""
+    quantization error of the exact psum — for several seeds."""
     out = _run(PRELUDE + """
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.comm import transport
 from repro.comm.codecs import AffineCodec
 
 codec = AffineCodec(4)        # coarse wire makes any bias glaring
-mesh = compat_make_mesh((2,), ("data",), devices=jax.devices()[:2])
+mesh = make_mesh((2,), ("data",), devices=jax.devices()[:2])
 
 def rounds(x, keys):
     def one(e, key):
@@ -237,7 +236,7 @@ def rounds(x, keys):
     return sums
 
 sm = shard_map(rounds, mesh=mesh, in_specs=(P("data"), P(None, "data")),
-               out_specs=P(None, "data"), check_rep=False)
+               out_specs=P(None, "data"), check_vma=False)
 
 for seed in (0, 1, 2):
     x = jax.random.normal(jax.random.PRNGKey(100 + seed), (4, 64)) * 2.0
@@ -308,7 +307,7 @@ from repro.comm.codecs import GridCodec
 from repro.comm.transport import PaddedWire
 from repro.parallel import stage_parallel as SP
 
-mesh = compat_make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 V, h, L, C = 64, 32, 4, 4
 grids = {b: quantize.uniform_grid(b, -2.0, 6.0) for b in (4, 8, 16)}
 wire = PaddedWire.from_grids(grids)
@@ -367,7 +366,7 @@ from repro.comm.controller import stage_ring_edges
 from repro.graph.datasets import tiny
 from repro.parallel import stage_parallel as SP
 
-mesh = compat_make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 ds = tiny(V=64)
 X = ds.augmented(4)
 key = jax.random.PRNGKey(0)

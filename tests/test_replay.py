@@ -38,11 +38,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.core import quantize
 from repro.parallel import stage_parallel as SP
-mesh = compat_make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
 """
 
 
@@ -367,7 +367,7 @@ os.environ["REPRO_KERNELS"] = "interpret"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.pdadmm import ADMMConfig
 from repro.core import quantize
 from repro.comm.codecs import codec_for_grid
@@ -375,7 +375,7 @@ from repro.parallel import stage_parallel as SP
 from repro.analysis.replay import calibrate, replay
 
 V, h, L, C, iters = 128, 32, 8, 4, 10
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = ADMMConfig(nu=1e-2, rho=1.0, quantize_p=True, quantize_q=True,
                  grid=quantize.uniform_grid(8, -2.0, 6.0))
 key = jax.random.PRNGKey(0)

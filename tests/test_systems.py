@@ -57,11 +57,11 @@ import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.ckpt.manager import CheckpointManager
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 mgr = CheckpointManager({str(tmp_path)!r}, keep=3)
 tree = {{"w": jnp.arange(8.0).reshape(4, 2)}}
 mgr.save(3, tree)
-mesh = compat_make_mesh((2,), ("data",))
+mesh = make_mesh((2,), ("data",))
 sh = {{"w": NamedSharding(mesh, P("data"))}}
 restored, m = mgr.restore(tree, shardings=sh)
 assert restored["w"].sharding.is_equivalent_to(sh["w"], 2), restored["w"].sharding
