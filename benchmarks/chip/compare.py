@@ -1,0 +1,164 @@
+"""The comparison that decides ``correct``: the program's state after the
+first chunk of the window's own program against the plain reference.
+
+Readings, each a relative gap against the reference:
+
+* ``logits``: ||a - b|| / ||b|| of the labelled nodes' logits, the first C
+  columns of the last layer's z: the model's answer on the nodes the loss
+  sees;
+* ``risk``: |a - b| / |b| of the loss, the summed cross-entropy of those
+  logits;
+* one per state leaf (``p``, ``W``, ``b``, ``z``, ``q``, ``u``):
+  ||a - b|| / ||b|| over the whole leaf, all layers;
+* ``z_tail``: the same over the last ``V mod 256`` rows of every layer's z,
+  the masked edge block of the z-update kernel's 256-row tile, which a
+  whole-leaf norm would dilute;
+* ``residual``: the largest |a_k - b_k| over the chunk's iterations k of
+  the primal residual sqrt(sum_l ||p_{l+1} - q_l||^2), over the largest
+  b_k;
+* ``move``: how far the chunk moved each state leaf (p, W, b, z, q, u,
+  each over all layers, as the program's state holds it) from its own
+  start, the program's from the program's start and the reference's from
+  the reference's: the worst leaf's gap between the two norms of the
+  change, | ||a - a0|| - ||b - b0|| |, over the reference's norm of that
+  change or of the median leaf's, whichever is larger. A leaf that the
+  reference moves by less than ``STILL`` of the median leaf's change is
+  left out. ``move.<leaf>`` is the same for one leaf.
+
+The state gaps compare where the two iterates end; ``move`` compares how
+far each went, so a start that rounds differently does not hide a state
+that never moved.
+
+A cell's limits file names the readings that decide ``correct``; the others
+are printed for the record (PERF.md says why each is or is not compared).
+"""
+from __future__ import annotations
+
+import math
+from types import SimpleNamespace
+from typing import Dict, List, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+LEAVES = ("p", "W", "b", "z", "q", "u")
+EDGE_TILE = 256
+STILL = 1e-3
+
+
+def tail_rows(n_nodes: int) -> int:
+    return n_nodes % EDGE_TILE or EDGE_TILE
+
+
+def stacked(layers: Sequence) -> SimpleNamespace:
+    """A list of per-layer leaves as leaves indexed by layer."""
+    return SimpleNamespace(**{k: [getattr(l, k) for l in layers]
+                              for k in LEAVES})
+
+
+def gaps(program, reference: Sequence, program_res: Sequence[float],
+         reference_res: Sequence[float], labels, train_mask,
+         n_classes: int, program_start=None, reference_start=None
+         ) -> Dict[str, float]:
+    """``program`` and ``program_start`` hold each leaf indexed by layer (an
+    [L, ...] array or a list, by attribute); ``reference`` and
+    ``reference_start`` are lists of per-layer leaves, on the host or the
+    device. ``move`` is read when both starts are given. The sums run on the
+    device in float32, one layer at a time."""
+    train = np.flatnonzero(np.asarray(train_mask))
+    lab = jnp.asarray(np.asarray(labels)[train])
+    a = jnp.asarray(program.z[-1], jnp.float32)[train, :n_classes]
+    b = jnp.asarray(reference[-1].z)[train, :n_classes].astype(jnp.float32)
+    num, den = _sq_sums(a, b)
+    risk_a, risk_b = _risk(a, lab), _risk(b, lab)
+    out = {"logits": math.sqrt(float(num) / float(den)),
+           "risk": abs(float(risk_a) - float(risk_b)) / abs(float(risk_b))}
+    a_res = np.asarray(program_res, np.float64)
+    b_res = np.asarray(reference_res, np.float64)
+    out["residual"] = float(np.max(np.abs(a_res - b_res))
+                            / np.max(np.abs(b_res)))
+    sums = {k: [0.0, 0.0] for k in LEAVES + ("z_tail",)}
+    for l, ref_layer in enumerate(reference):
+        for k in LEAVES:
+            b = jnp.asarray(getattr(ref_layer, k))
+            a = jnp.asarray(getattr(program, k)[l], jnp.float32)
+            num, den = _sq_sums(a, b)
+            sums[k][0] += float(num)
+            sums[k][1] += float(den)
+            if k == "z":
+                t = tail_rows(a.shape[0])
+                num, den = _sq_sums(a[-t:], b[-t:])
+                sums["z_tail"][0] += float(num)
+                sums["z_tail"][1] += float(den)
+    for k, (num, den) in sums.items():
+        out[k] = math.sqrt(num / den) if den > 0 else math.inf
+    if program_start is not None and reference_start is not None:
+        out.update(moves(program, program_start, reference, reference_start))
+    return out
+
+
+def change_norms(program, program_start, reference: Sequence,
+                 reference_start: Sequence) -> List[Tuple[str, int, float,
+                                                          float]]:
+    """(leaf, layer, ||a - a0||, ||b - b0||) for every leaf of every layer."""
+    norms = []
+    for l, (ref, ref0) in enumerate(zip(reference, reference_start)):
+        for k in LEAVES:
+            da, db = _change(jnp.asarray(getattr(program, k)[l]),
+                             jnp.asarray(getattr(program_start, k)[l]),
+                             jnp.asarray(getattr(ref, k)),
+                             jnp.asarray(getattr(ref0, k)))
+            norms.append((k, l, float(da), float(db)))
+    return norms
+
+
+def moves(program, program_start, reference: Sequence,
+          reference_start: Sequence) -> Dict[str, float]:
+    """``move`` and ``move.<leaf>`` (see the module's docstring)."""
+    sq = {k: [0.0, 0.0] for k in LEAVES}
+    for k, _, da, db in change_norms(program, program_start, reference,
+                                     reference_start):
+        sq[k][0] += da * da
+        sq[k][1] += db * db
+    norms = {k: (math.sqrt(a), math.sqrt(b)) for k, (a, b) in sq.items()}
+    median = float(np.median([db for _, db in norms.values()]))
+    out = {"move": 0.0}
+    for k, (da, db) in norms.items():
+        g = 0.0 if db <= STILL * median else abs(da - db) / max(db, median)
+        out[f"move.{k}"] = g
+        out["move"] = max(out["move"], g)
+    return out
+
+
+@jax.jit
+def _change(a, a0, b, b0):
+    f = lambda x, x0: jnp.sqrt(jnp.sum(jnp.square(
+        x.astype(jnp.float32) - x0.astype(jnp.float32))))
+    return f(a, a0), f(b, b0)
+
+
+@jax.jit
+def _risk(logits, labels):
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.sum(jnp.take_along_axis(logp, labels[:, None], axis=-1))
+
+
+@jax.jit
+def _sq_sums(a, b):
+    b = b.astype(jnp.float32)
+    return jnp.sum(jnp.square(a - b)), jnp.sum(jnp.square(b))
+
+
+def judge(values: Dict[str, float], limits: Dict[str, float]
+          ) -> Tuple[bool, List[str]]:
+    """True when every number is finite and within its limit; one line per
+    number, ``name value (limit x)``."""
+    ok, lines = True, []
+    for name, limit in limits.items():
+        v = values.get(name, math.nan)
+        good = math.isfinite(v) and v <= limit
+        ok &= good
+        lines.append(f"{name} {v:.6e} (limit {limit:g})"
+                     + ("" if good else " OVER"))
+    return ok, lines
