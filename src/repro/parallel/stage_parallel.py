@@ -245,6 +245,10 @@ def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
     uk = config.use_kernels
 
     def stage_body(carry, Xp, labels, label_mask, widths=None, ctl=None):
+        # Each phase runs under a `jax.named_scope` ("admm.<solve>"), which
+        # names it in the op_name metadata of every HLO instruction it
+        # lowers to, so a device trace can be summed per solve. The scopes
+        # are metadata only: the compiled program is the same without them.
         if overlap:
             st_c, (q_fly, u_fly) = carry
         else:
@@ -257,52 +261,55 @@ def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
         gidx = sidx * m_loc + jnp.arange(m_loc)          # global layer ids
         is_first = (gidx == 0)[:, None, None]
         is_last = (gidx == L - 1)[:, None, None]
-        if cex is not None:
-            # active widths: mine for encodes, the ORIGINATING stage's for
-            # decodes (everyone reads the same replicated table)
-            sel_q, sel_p = widths[0, sidx], widths[1, sidx]
-            sel_q_prev = widths[0, jnp.mod(sidx - 1, n_stages)]
-            sel_p_next = widths[1, jnp.mod(sidx + 1, n_stages)]
 
         # ---- neighbor exchange (prev iteration values) -------------------
         # overlap: the ppermutes were issued at the END of the previous
         # iteration (same values — st.q/st.u ARE that iteration's outputs);
         # only decode+splice happens here.
-        if sentinel:
-            # a carried slab was stamped by last tick's controls
-            exp_qu = ctl.seqno - 1 if overlap else ctl.seqno
-            slab_shape = st.q[-1:].shape
-            if not overlap:
-                q_fly = sx_q.start(st.q[-1:], ctl, +1,
-                                   sel=sel_q if cex is not None else None)
-                u_fly = sx_u.start(st.u[-1:], ctl, +1)
-            qb, ok_q = sx_q.finish(
-                q_fly, ctl, exp_qu, slab_shape, st.q.dtype, good.q, +1,
-                sel_src=sel_q_prev if cex is not None else None)
-            ub, ok_u = sx_u.finish(u_fly, ctl, exp_qu, slab_shape,
-                                   st.u.dtype, good.u, +1)
-            q_prev = jnp.concatenate([qb, st.q[:-1]], axis=0)
-            u_prev = jnp.concatenate([ub, st.u[:-1]], axis=0)
-            good_q, good_u = qb, ub
-        elif overlap:
-            q_prev = (cex.finish_shift_from_prev(q_fly, st.q, sel_q_prev)
-                      if cex is not None
-                      else ex_q.finish_shift_from_prev(q_fly, st.q))
-            u_prev = ex_u.finish_shift_from_prev(u_fly, st.u)
-        elif cex is not None:
-            q_prev = cex.shift_from_prev(st.q, sel_q, sel_q_prev)
-            u_prev = ex_u.shift_from_prev(st.u)
-        else:
-            q_prev = ex_q.shift_from_prev(st.q)
-            u_prev = ex_u.shift_from_prev(st.u)
-        q_prev = jnp.where(is_first, 0.0, q_prev)        # layer 0 has no prev
-        u_prev = jnp.where(is_first, 0.0, u_prev)
+        with jax.named_scope("admm.exchange"):
+            if cex is not None:
+                # active widths: mine for encodes, the ORIGINATING stage's
+                # for decodes (everyone reads the same replicated table)
+                sel_q, sel_p = widths[0, sidx], widths[1, sidx]
+                sel_q_prev = widths[0, jnp.mod(sidx - 1, n_stages)]
+                sel_p_next = widths[1, jnp.mod(sidx + 1, n_stages)]
+            if sentinel:
+                # a carried slab was stamped by last tick's controls
+                exp_qu = ctl.seqno - 1 if overlap else ctl.seqno
+                slab_shape = st.q[-1:].shape
+                if not overlap:
+                    q_fly = sx_q.start(st.q[-1:], ctl, +1,
+                                       sel=sel_q if cex is not None else None)
+                    u_fly = sx_u.start(st.u[-1:], ctl, +1)
+                qb, ok_q = sx_q.finish(
+                    q_fly, ctl, exp_qu, slab_shape, st.q.dtype, good.q, +1,
+                    sel_src=sel_q_prev if cex is not None else None)
+                ub, ok_u = sx_u.finish(u_fly, ctl, exp_qu, slab_shape,
+                                       st.u.dtype, good.u, +1)
+                q_prev = jnp.concatenate([qb, st.q[:-1]], axis=0)
+                u_prev = jnp.concatenate([ub, st.u[:-1]], axis=0)
+                good_q, good_u = qb, ub
+            elif overlap:
+                q_prev = (cex.finish_shift_from_prev(q_fly, st.q, sel_q_prev)
+                          if cex is not None
+                          else ex_q.finish_shift_from_prev(q_fly, st.q))
+                u_prev = ex_u.finish_shift_from_prev(u_fly, st.u)
+            elif cex is not None:
+                q_prev = cex.shift_from_prev(st.q, sel_q, sel_q_prev)
+                u_prev = ex_u.shift_from_prev(st.u)
+            else:
+                q_prev = ex_q.shift_from_prev(st.q)
+                u_prev = ex_u.shift_from_prev(st.u)
+            q_prev = jnp.where(is_first, 0.0, q_prev)    # layer 0 has no prev
+            u_prev = jnp.where(is_first, 0.0, u_prev)
 
         # ---- entry residuals r = z - pW - b (ONE fused op per layer);
         # chained through the whole update family below, so no solver ever
         # recomputes the linear map and backtracking trials are matmul-free.
-        r = jax.vmap(lambda p_, W_, b_, z_: sp._residual(p_, W_, b_, z_, uk))(
-            st.p, st.W, st.b, st.z)
+        with jax.named_scope("admm.residual"):
+            r = jax.vmap(
+                lambda p_, W_, b_, z_: sp._residual(p_, W_, b_, z_, uk))(
+                    st.p, st.W, st.b, st.z)
 
         # ---- p-update (masked for layer 0: p0 = Xp fixed) -----------------
         def p_upd(p_, W_, b_, z_, qp, up, r_):
@@ -310,22 +317,24 @@ def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
                                     config.tau0, grid=p_grid, r0=r_,
                                     use_kernels=uk)
             return pn, rn
-        p_new, r_new = jax.vmap(p_upd)(st.p, st.W, st.b, st.z, q_prev,
-                                       u_prev, r)
-        p = jnp.where(is_first, Xp[None], p_new)
-        r = jnp.where(is_first, r, r_new)    # layer 0 keeps the Xp residual
+        with jax.named_scope("admm.p"):
+            p_new, r_new = jax.vmap(p_upd)(st.p, st.W, st.b, st.z, q_prev,
+                                           u_prev, r)
+            p = jnp.where(is_first, Xp[None], p_new)
+            r = jnp.where(is_first, r, r_new)  # layer 0 keeps the Xp residual
 
         # overlap: issue the backward p exchange as soon as the p-solve is
         # done — the W/b/z solves below never read p_next, so the message
         # rides under them and is finished right before the q-update.
         if overlap:
-            if sentinel:
-                p_fly = sx_p.start(p[:1], ctl, -1,
-                                   sel=sel_p if cex is not None else None)
-            else:
-                p_fly = (cex.start_shift_from_next(p, sel_p)
-                         if cex is not None
-                         else ex_p.start_shift_from_next(p))
+            with jax.named_scope("admm.exchange"):
+                if sentinel:
+                    p_fly = sx_p.start(p[:1], ctl, -1,
+                                       sel=sel_p if cex is not None else None)
+                else:
+                    p_fly = (cex.start_shift_from_next(p, sel_p)
+                             if cex is not None
+                             else ex_p.start_shift_from_next(p))
 
         # ---- W-update ------------------------------------------------------
         def W_upd(p_, W_, b_, z_, qp, up, r_):
@@ -335,114 +344,126 @@ def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
                                     config.tau0, first=False, r0=r_,
                                     use_kernels=uk)
             return Wn, rn
-        W, r = jax.vmap(W_upd)(p, st.W, st.b, st.z, q_prev, u_prev, r)
+        with jax.named_scope("admm.W"):
+            W, r = jax.vmap(W_upd)(p, st.W, st.b, st.z, q_prev, u_prev, r)
 
         # ---- b-update (exact: b += mean r; matmul-free) -------------------
-        db = jnp.mean(r, axis=1)
-        b = st.b + db
-        r = r - db[:, None, :]
+        with jax.named_scope("admm.b"):
+            db = jnp.mean(r, axis=1)
+            b = st.b + db
+            r = r - db[:, None, :]
 
         # ---- z-update (a = pW + b = z - r; matmul-free) --------------------
-        a = st.z - r
-        z_hidden = sp._zupdate(a, st.q, st.z, nu, uk)
-        z_last = _fista_last(a, st.z, labels, label_mask, nu, n_classes,
-                             config.fista_iters, use_kernels=uk)
-        z = jnp.where(is_last, z_last, z_hidden)
+        with jax.named_scope("admm.z"):
+            a = st.z - r
+            z_hidden = sp._zupdate(a, st.q, st.z, nu, uk)
+        with jax.named_scope("admm.z_last"):
+            z_last = _fista_last(a, st.z, labels, label_mask, nu, n_classes,
+                                 config.fista_iters, use_kernels=uk)
+        with jax.named_scope("admm.z"):
+            z = jnp.where(is_last, z_last, z_hidden)
 
         # ---- q-update (needs p_{l+1} = next layer's NEW p) -------------------
-        if sentinel:
-            # the backward p slab always flies within its own tick
-            if not overlap:
-                p_fly = sx_p.start(p[:1], ctl, -1,
-                                   sel=sel_p if cex is not None else None)
-            pb, ok_p = sx_p.finish(
-                p_fly, ctl, ctl.seqno, p[:1].shape, p.dtype, good.p, -1,
-                sel_src=sel_p_next if cex is not None else None)
-            p_next = jnp.concatenate([p[1:], pb], axis=0)
-            good_p = pb
-        elif cex is not None:
-            p_next = (cex.finish_shift_from_next(p_fly, p, sel_p_next)
-                      if overlap else
-                      cex.shift_from_next(p, sel_p, sel_p_next))
-        else:
-            p_next = (ex_p.finish_shift_from_next(p_fly, p) if overlap
-                      else ex_p.shift_from_next(p))
-        fz = relu(z)
-        q = jax.vmap(sp.update_q, in_axes=(0, 0, 0, None, None, None))(
-            p_next, st.u, fz, nu, rho, q_grid)
-        q = jnp.where(is_last, st.q, q)                  # no q for layer L-1
+        with jax.named_scope("admm.exchange"):
+            if sentinel:
+                # the backward p slab always flies within its own tick
+                if not overlap:
+                    p_fly = sx_p.start(p[:1], ctl, -1,
+                                       sel=sel_p if cex is not None else None)
+                pb, ok_p = sx_p.finish(
+                    p_fly, ctl, ctl.seqno, p[:1].shape, p.dtype, good.p, -1,
+                    sel_src=sel_p_next if cex is not None else None)
+                p_next = jnp.concatenate([p[1:], pb], axis=0)
+                good_p = pb
+            elif cex is not None:
+                p_next = (cex.finish_shift_from_next(p_fly, p, sel_p_next)
+                          if overlap else
+                          cex.shift_from_next(p, sel_p, sel_p_next))
+            else:
+                p_next = (ex_p.finish_shift_from_next(p_fly, p) if overlap
+                          else ex_p.shift_from_next(p))
+        with jax.named_scope("admm.q"):
+            fz = relu(z)
+            q = jax.vmap(sp.update_q, in_axes=(0, 0, 0, None, None, None))(
+                p_next, st.u, fz, nu, rho, q_grid)
+            q = jnp.where(is_last, st.q, q)              # no q for layer L-1
 
         # ---- dual update ------------------------------------------------------
-        r = jnp.where(is_last, 0.0, p_next - q)
-        u = st.u + rho * r
+        with jax.named_scope("admm.u"):
+            r = jnp.where(is_last, 0.0, p_next - q)
+            u = st.u + rho * r
 
         # overlap: q and u now hold exactly the values the NEXT iteration's
         # entry exchange would send — start the forward shifts here so the
         # ring messages fly under the metrics psums below and next entry's
         # residual computation, and carry the encoded slabs across.
         if overlap:
-            if sentinel:
-                new_q_fly = sx_q.start(q[-1:], ctl, +1,
-                                       sel=sel_q if cex is not None else None)
-                new_u_fly = sx_u.start(u[-1:], ctl, +1)
-                if faults is not None:
-                    # delayed delivery: MY carry keeps the stale pair when
-                    # my upstream source's send is late (detected next tick
-                    # by the stale seqno in the carried header)
-                    late = ctl.delay[jnp.mod(sidx - 1, n_stages)]
-                    hold = lambda old, fresh: jax.tree.map(
-                        lambda o, f: jnp.where(late, o, f), old, fresh)
-                    new_q_fly = hold(q_fly, new_q_fly)
-                    new_u_fly = hold(u_fly, new_u_fly)
-                out_fly = (new_q_fly, new_u_fly)
-            else:
-                out_fly = ((cex.start_shift_from_prev(q, sel_q)
-                            if cex is not None
-                            else ex_q.start_shift_from_prev(q)),
-                           ex_u.start_shift_from_prev(u))
+            with jax.named_scope("admm.exchange"):
+                if sentinel:
+                    new_q_fly = sx_q.start(
+                        q[-1:], ctl, +1,
+                        sel=sel_q if cex is not None else None)
+                    new_u_fly = sx_u.start(u[-1:], ctl, +1)
+                    if faults is not None:
+                        # delayed delivery: MY carry keeps the stale pair
+                        # when my upstream source's send is late (detected
+                        # next tick by the stale seqno in the carried header)
+                        late = ctl.delay[jnp.mod(sidx - 1, n_stages)]
+                        hold = lambda old, fresh: jax.tree.map(
+                            lambda o, f: jnp.where(late, o, f), old, fresh)
+                        new_q_fly = hold(q_fly, new_q_fly)
+                        new_u_fly = hold(u_fly, new_u_fly)
+                    out_fly = (new_q_fly, new_u_fly)
+                else:
+                    out_fly = ((cex.start_shift_from_prev(q, sel_q)
+                                if cex is not None
+                                else ex_q.start_shift_from_prev(q)),
+                               ex_u.start_shift_from_prev(u))
 
         # ---- metrics ------------------------------------------------------------
-        res_sq = jax.lax.psum(jnp.sum(r * r), ("model",) + dp)
-        # per-stage primal residual (the controller's per-boundary signal):
-        # each stage drops its local ||p_next - q||^2 into its slot, psum
-        # assembles the replicated [n_stages] vector
-        seg = jnp.zeros((n_stages,), jnp.float32).at[sidx].set(
-            jnp.sum(r * r))
-        seg = jax.lax.psum(seg, ("model",) + dp)
-        risk_val = _masked_ce_val(z[-1], labels, label_mask, n_classes)
-        risk_val = jnp.where(sidx == n_stages - 1, risk_val, 0.0)
-        risk_val = jax.lax.psum(risk_val, "model")
-        risk_val = jax.lax.psum(risk_val, dp) if dp else risk_val
-        lag = _local_lagrangian(StackState(p, W, b, z, q, u),
-                                r + (z - st.z), q_prev, u_prev,
-                                is_first, is_last, nu, rho)
-        lag = jax.lax.psum(lag, ("model",) + dp) + risk_val
-        new = StackState(p, W, b, z, q, u)
-        metrics = {"residual": jnp.sqrt(res_sq), "objective": lag,
-                   "stage_residuals": jnp.sqrt(seg)}
-        if sentinel:
-            axes = ("model",) + dp
-            i32 = jnp.int32
+        with jax.named_scope("admm.metrics"):
+            res_sq = jax.lax.psum(jnp.sum(r * r), ("model",) + dp)
+            # per-stage primal residual (the controller's per-boundary
+            # signal): each stage drops its local ||p_next - q||^2 into its
+            # slot, psum assembles the replicated [n_stages] vector
+            seg = jnp.zeros((n_stages,), jnp.float32).at[sidx].set(
+                jnp.sum(r * r))
+            seg = jax.lax.psum(seg, ("model",) + dp)
+            risk_val = _masked_ce_val(z[-1], labels, label_mask, n_classes)
+            risk_val = jnp.where(sidx == n_stages - 1, risk_val, 0.0)
+            risk_val = jax.lax.psum(risk_val, "model")
+            risk_val = jax.lax.psum(risk_val, dp) if dp else risk_val
+            lag = _local_lagrangian(StackState(p, W, b, z, q, u),
+                                    r + (z - st.z), q_prev, u_prev,
+                                    is_first, is_last, nu, rho)
+            lag = jax.lax.psum(lag, ("model",) + dp) + risk_val
+            new = StackState(p, W, b, z, q, u)
+            metrics = {"residual": jnp.sqrt(res_sq), "objective": lag,
+                       "stage_residuals": jnp.sqrt(seg)}
+            if sentinel:
+                axes = ("model",) + dp
+                i32 = jnp.int32
 
-            def all_finite(t):
-                return jax.lax.psum(
-                    jnp.sum(~jnp.isfinite(t), dtype=i32), axes) == 0
+                def all_finite(t):
+                    return jax.lax.psum(
+                        jnp.sum(~jnp.isfinite(t), dtype=i32), axes) == 0
 
-            metrics["health"] = {
-                "wire_bad": jnp.stack(
-                    [jax.lax.psum((~o).astype(i32), axes)
-                     for o in (ok_q, ok_u, ok_p)]),
-                "p_finite": all_finite(p), "W_finite": all_finite(W),
-                "b_finite": all_finite(b), "z_finite": all_finite(z),
-                "residual_finite": jnp.isfinite(res_sq) & jnp.isfinite(lag),
-                "objective_spike": (
-                    jnp.isfinite(ctl.prev_obj)
-                    & (lag > ctl.prev_obj
-                       + FT.SPIKE_TOL * (1.0 + jnp.abs(ctl.prev_obj)))),
-            }
-            out_state = (new, FT.GoodSlabs(q=good_q, u=good_u, p=good_p))
-        else:
-            out_state = new
+                metrics["health"] = {
+                    "wire_bad": jnp.stack(
+                        [jax.lax.psum((~o).astype(i32), axes)
+                         for o in (ok_q, ok_u, ok_p)]),
+                    "p_finite": all_finite(p), "W_finite": all_finite(W),
+                    "b_finite": all_finite(b), "z_finite": all_finite(z),
+                    "residual_finite": (jnp.isfinite(res_sq)
+                                        & jnp.isfinite(lag)),
+                    "objective_spike": (
+                        jnp.isfinite(ctl.prev_obj)
+                        & (lag > ctl.prev_obj
+                           + FT.SPIKE_TOL * (1.0 + jnp.abs(ctl.prev_obj)))),
+                }
+                out_state = (new, FT.GoodSlabs(q=good_q, u=good_u, p=good_p))
+            else:
+                out_state = new
         return ((out_state, out_fly) if overlap else out_state), metrics
 
     def _local_lagrangian(st, rr, q_prev, u_prev, is_first, is_last, nu, rho):
