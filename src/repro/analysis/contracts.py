@@ -253,20 +253,31 @@ def _walk_jaxprs(jaxpr):
             yield from _walk_jaxprs(sub)
 
 
-def _pallas_counts(jaxpr) -> Dict[str, int]:
-    """pallas_call eqns per kernel-body base name (vmap's ``_batched``
-    suffix normalized away)."""
-    out: Dict[str, int] = {}
+def _pallas_eqns(jaxpr):
+    """(kernel-body base name, eqn) of every pallas_call, vmap's
+    ``_batched`` suffix normalized away."""
     for jx in _walk_jaxprs(jaxpr):
         for eqn in jx.eqns:
             if eqn.primitive.name != "pallas_call":
                 continue
             info = eqn.params.get("name_and_src_info")
-            name = getattr(info, "name", None) or \
-                str(eqn.params.get("name", "?"))
+            name = getattr(info, "name", None) or eqn.params.get("name")
+            if not name:
+                # this JAX keeps the body's name only in its debug info:
+                # "<name> at <file>:<line>"
+                src = getattr(eqn.params["jaxpr"].debug_info,
+                              "func_src_info", None) or "?"
+                name = src.split(" at ", 1)[0]
             if name.endswith("_batched"):
                 name = name[:-len("_batched")]
-            out[name] = out.get(name, 0) + 1
+            yield name, eqn
+
+
+def _pallas_counts(jaxpr) -> Dict[str, int]:
+    """pallas_call eqns per kernel-body base name."""
+    out: Dict[str, int] = {}
+    for name, _ in _pallas_eqns(jaxpr):
+        out[name] = out.get(name, 0) + 1
     return out
 
 
@@ -412,6 +423,16 @@ class ProgramView:
         return self._memo("pallas", lambda: _pallas_counts(self.jaxpr))
 
     @property
+    def fista_operands(self):
+        """Shapes of the z_prev, z_cur and a operands of every fused FISTA
+        step, in trace order."""
+        from repro.kernels import ops
+        kernel = ops.KERNEL_NAMES["fista_zlast"]
+        return self._memo("fista", lambda: [
+            tuple(tuple(v.aval.shape) for v in eqn.invars[:3])
+            for name, eqn in _pallas_eqns(self.jaxpr) if name == kernel])
+
+    @property
     def ppermute_moves(self):
         return self._memo("moves", lambda: _ppermute_moves(self.jaxpr))
 
@@ -543,6 +564,21 @@ def _dispatch_ragged(view):
                f"tile-aligned plan {view.plan.pallas_calls} — "
                f"silent ref fallback",
                {"ragged_V": ragged.spec.V, "got": got})
+
+
+@contract("dispatch.fista_operands", severity="error",
+          description="every fused FISTA step reads one layer's logit "
+                      "columns, [pad(V_loc), pad(C)] (not the layer stack, "
+                      "not the full width)")
+def _dispatch_fista_operands(view):
+    want = view.plan.fista_operand
+    if want is None:
+        return
+    got = sorted({s for call in view.fista_operands for s in call})
+    if got != [want]:
+        yield (f"fused FISTA steps read operands {got}, plan says one "
+               f"layer's logit columns {want}",
+               {"got": got, "want": want})
 
 
 # ---------------------------------------------------------------------------

@@ -689,11 +689,9 @@ def calibrate(mesh, *, V: int = 128, h: int = 32, n_classes: int = 4,
 
     def solver_probe(p, W, b, z, q, u, labels, mask):
         pn, Wn, a2, zn, qn, un = jax.vmap(layer_fam)(p, W, b, z, q, u)
-        m = a2.shape[0]
-        zl = sp.update_z_last(a2.reshape(-1, h), z.reshape(-1, h),
-                              jnp.tile(labels, m), jnp.tile(mask, m), 1.0,
-                              fista_iters, n_classes=n_classes,
-                              use_kernels=True)
+        # the step solves z_L on the last local layer only
+        zl = sp.update_z_last(a2[-1], z[-1], labels, mask, 1.0, fista_iters,
+                              n_classes=n_classes, use_kernels=True)
         return pn, Wn, zn, zl, qn, un
 
     m_loc = 2

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quantize import QuantGrid
+from repro.kernels.fista_zlast import prox_carry_weight
 
 
 def linear(p, W, b):
@@ -383,12 +384,25 @@ def update_z_last(a, z_old, labels, label_mask, nu, n_iters: int = 15,
     FISTA iteration under the `REPRO_KERNELS` policy); ``use_kernels=False``
     stays on the local jnp loop. ``update_z_last_reference`` keeps the
     pre-kernel code as the ground-truth oracle.
+
+    With ``n_classes`` C below the width (the head-folded layout), FISTA
+    runs on the C logit columns only. The other columns see no softmax and
+    a zero one-hot, so their gradient is ν(y − a) and the solve leaves them
+    at α·z_old + (1 − α)·a, α = ``prox_carry_weight(n_iters, ν)``: the same
+    map as iterating them, rounded once instead of per step.
     """
+    C = a.shape[-1] if n_classes is None else n_classes
+    if C < a.shape[-1]:
+        alpha = prox_carry_weight(n_iters, float(nu))
+        zc = update_z_last(a[:, :C], z_old[:, :C], labels, label_mask, nu,
+                           n_iters, use_kernels=use_kernels)
+        rest = alpha * z_old[:, C:] + (1.0 - alpha) * a[:, C:]
+        return jnp.concatenate([zc, rest], axis=1)
     if use_kernels:
         from repro.kernels import ops
         return ops.fista_zlast(a, z_old, labels, label_mask, nu=nu,
-                               n_iters=n_iters, n_classes=n_classes)
-    return fista_ce(a, z_old, labels, label_mask, nu, n_iters, n_classes)
+                               n_iters=n_iters)
+    return fista_ce(a, z_old, labels, label_mask, nu, n_iters)
 
 
 def update_z_last_reference(a, z_old, labels, label_mask, nu,

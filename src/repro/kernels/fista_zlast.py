@@ -14,10 +14,15 @@ so the per-iteration extrapolation weight (t_k − 1)/t_{k+1} is precomputed
 host-side (`momentum_schedule`) and baked into each dispatch as a static
 scalar: the kernel needs no scalar prefetch and no carried t.
 
-Columns ≥ `n_classes` (tile padding, or the distributed runtime's
-head-folded layout where only z[:, :C] carries logits) are excluded from the
-softmax/CE terms but still follow the proximal flow — exactly the padded-
-gradient semantics of `stage_parallel`'s risk.
+Columns ≥ `n_classes` (tile padding, or a caller's columns past the
+logits) are excluded from the softmax/CE terms but still follow the
+proximal flow — exactly the padded-gradient semantics of `stage_parallel`'s
+risk. That flow has data-independent coefficients: each step maps
+y ↦ s·y + (1 − s)·a, s = 1/(1+ν), so such a column ends the solve at
+α·z_old + (1 − α)·a, α = `prox_carry_weight(n_iters, nu)`. The head-folded
+layout, where only z[:, :C] of h columns carries logits, therefore hands
+this kernel its C logit columns alone and writes the rest in closed form
+(`subproblems.update_z_last`).
 """
 from __future__ import annotations
 
@@ -40,6 +45,19 @@ def momentum_schedule(n_iters: int) -> list:
         ms.append((t - 1.0) / t_new)
         t = t_new
     return ms
+
+
+def prox_carry_weight(n_iters: int, nu: float) -> float:
+    """z_old's weight α in the result of the solve on a column outside the
+    softmax, where the gradient is ν(y − a): z = α·z_old + (1 − α)·a.
+    Each step keeps the iterate an affine mix of z_old and a whose weights
+    sum to 1, so the scalar weight of z_old runs through the same momentum
+    schedule and step 1/(1+ν) as the kernel. Python floats: a constant."""
+    step = 1.0 / (1.0 + nu)
+    prev = cur = 1.0                   # z_prev = z_cur = z_old at the start
+    for mom in momentum_schedule(n_iters):
+        prev, cur = cur, step * (cur + mom * (cur - prev))
+    return cur
 
 
 def _fista_step_kernel(zp_ref, zc_ref, a_ref, lab_ref, mask_ref, out_ref, *,

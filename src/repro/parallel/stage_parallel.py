@@ -21,7 +21,7 @@ identical (no load imbalance — the paper's equal-width large-scale setup).
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -137,17 +137,16 @@ def _masked_ce_val(z, labels, label_mask, n_classes: int):
 
 def _fista_last(a, z_old, labels, label_mask, nu, n_classes, n_iters,
                 use_kernels: bool = True):
-    """Head-folded z_L solve for a [M, V, h] layer stack: ONE
-    `subproblems.update_z_last` dispatch over the flattened rows
-    (labels/mask tiled per layer — the momentum schedule is row-independent,
-    so flattening is exact)."""
-    m = a.shape[0]
-    h = a.shape[-1]
-    z = sp.update_z_last(
-        a.reshape(-1, h), z_old.reshape(-1, h),
-        jnp.tile(labels, m), jnp.tile(label_mask, m),
-        nu, n_iters, n_classes=n_classes, use_kernels=use_kernels)
-    return z.reshape(a.shape)
+    """Head-folded z_L solve of a stage's [M, V, h] layer stack, on the one
+    layer whose result can be kept: global layer L-1 is always a stage's
+    LAST local layer, so only ``a[-1]`` is solved and the caller selects it
+    on the stage that holds L-1 (every other layer, and that layer on every
+    other stage, takes the z-hidden update). Inside the layer
+    `subproblems.update_z_last` iterates on the C logit columns alone and
+    writes the rest in closed form. Returns the [V, h] solution."""
+    return sp.update_z_last(a[-1], z_old[-1], labels, label_mask, nu,
+                            n_iters, n_classes=n_classes,
+                            use_kernels=use_kernels)
 
 
 def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
@@ -361,7 +360,11 @@ def make_distributed_step(mesh: Mesh, L: int, n_classes: int,
             z_last = _fista_last(a, st.z, labels, label_mask, nu, n_classes,
                                  config.fista_iters, use_kernels=uk)
         with jax.named_scope("admm.z"):
-            z = jnp.where(is_last, z_last, z_hidden)
+            # a select over the stack, not an update of its last slab: XLA
+            # fuses it into the metrics' passes over z and writes z straight
+            # into the loop carry, where an in-place slab update costs a
+            # full copy of the stack into the carry
+            z = jnp.where(is_last, z_last[None], z_hidden)
 
         # ---- q-update (needs p_{l+1} = next layer's NEW p) -------------------
         with jax.named_scope("admm.exchange"):
@@ -818,6 +821,10 @@ class StepProgramPlan(NamedTuple):
         vmap ``_batched`` suffix normalized away) under the CURRENT
         ``REPRO_KERNELS`` policy; empty when the policy or
         ``config.use_kernels`` routes to the jnp oracles.
+      * `fista_operand` — the padded [rows, cols] of the z and a operands of
+        every fused FISTA step: one data shard's rows by the C logit
+        columns, [pad(V_loc), pad(C)] (the z_L solve covers one layer and
+        only its logits); None when the kernels are off.
       * `expects_xor` / `donate` / `takes_widths` / `sentinel` / `overlap`
         — presence flags for the fault injector's xor machinery, donation
         markers, the trailing widths table, headers, and the carried
@@ -827,6 +834,7 @@ class StepProgramPlan(NamedTuple):
     n_carried: int
     min_work_to_consumer: int
     pallas_calls: dict
+    fista_operand: Optional[Tuple[int, int]]
     expects_xor: bool
     donate: bool
     takes_widths: bool
@@ -903,14 +911,17 @@ def step_program_plan(mesh, L: int, n_classes: int, config: ADMMConfig, *,
                 if names is not None:
                     for name in names:
                         pallas[name] = pallas.get(name, 0) + 2
+        fista_operand = ops.padded_shape("fista_zlast", (r0, n_classes))
     else:
         pallas = {}
+        fista_operand = None
 
     return StepProgramPlan(
         edge_events=tuple(events),
         n_carried=2 if overlap else 0,
         min_work_to_consumer=2 if overlap else 0,
         pallas_calls=pallas,
+        fista_operand=fista_operand,
         expects_xor=faults is not None,
         donate=donate,
         takes_widths=wire is not None,

@@ -181,6 +181,46 @@ print("MUT_DISPATCH_OK")
     assert "MUT_DISPATCH_OK" in out
 
 
+def test_mutation_dispatch_fista_operands():
+    """dispatch.fista_operands pins the z_L solve to one layer's logit
+    columns. On a spec wider than one lane tile (h = 160 > 128 > C), a
+    full-width solve and the stack-wide solve over every local layer's
+    rows each trip exactly that key; the unmutated step is clean."""
+    out = _run(PRELUDE + """
+import dataclasses
+import jax.numpy as jnp
+os.environ["REPRO_KERNELS"] = "interpret"
+from repro.core import subproblems as sp
+from repro.parallel import stage_parallel as SP
+wide = dataclasses.replace(CT.get_spec("baseline"), name="wide", h=160,
+                           cache_probe=False, check_ragged=False)
+assert keys(CT.check_contracts(wide), "error") == []
+one_layer = SP._fista_last
+
+def full_width(a, z_old, labels, mask, nu, n_classes, n_iters,
+               use_kernels=True):
+    return one_layer(a, z_old, labels, mask, nu, a.shape[-1], n_iters,
+                     use_kernels)
+
+def stack_wide(a, z_old, labels, mask, nu, n_classes, n_iters,
+               use_kernels=True):
+    m, h = a.shape[0], a.shape[-1]
+    z = sp.update_z_last(a.reshape(-1, h), z_old.reshape(-1, h),
+                         jnp.tile(labels, m), jnp.tile(mask, m), nu,
+                         n_iters, n_classes=n_classes,
+                         use_kernels=use_kernels)
+    return z.reshape(a.shape)[-1]
+
+for mutant in (full_width, stack_wide):
+    SP._fista_last = mutant
+    f = CT.check_contracts(wide)
+    assert keys(f, "error") == ["dispatch.fista_operands"], keys(f, "error")
+SP._fista_last = one_layer
+print("MUT_FISTA_OK")
+""")
+    assert "MUT_FISTA_OK" in out
+
+
 def test_mutation_dtype_f64_leak():
     """dtype.no_f64 must bite on a program with float64 avals. The global
     x64 switch breaks the step's own scan before any contract runs (carry

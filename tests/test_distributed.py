@@ -116,6 +116,54 @@ print("MESH_F64_OK")
     assert "MESH_F64_OK" in out
 
 
+def test_z_last_solved_on_one_layer_f64():
+    """Each stage solves z_L on its last local layer only, and only the
+    stage holding layer L-1 keeps the result: after one step in float64,
+    on a (1, 1) and a (1, 4) mesh, layer L-1's z is the reference FISTA
+    solve of its a (the C logit columns; the rest as the prox-only solve)
+    and every other layer's z is the z-hidden update."""
+    out = _run(PRELUDE + """
+jax.config.update("jax_enable_x64", True)
+from repro.core import subproblems as sp
+from repro.core.pdadmm import ADMMConfig
+from repro.graph.datasets import synthetic
+from repro.parallel import stage_parallel as SP
+ds = synthetic("pubmed", seed=0, scale=0.02)          # V = 394, ragged
+X = ds.augmented(4)
+key = jax.random.PRNGKey(0)
+P0 = jax.random.normal(key, (X.shape[1], 64)) * jnp.sqrt(2.0 / X.shape[1])
+Xp = jnp.maximum(X @ P0, 0).astype(jnp.float64)
+L, C = 8, ds.n_classes
+cfg = ADMMConfig(nu=1e-2, rho=1.0, fista_iters=15,
+                 use_kernels=False)                   # every solve in f64
+labels, mask = ds.labels, ds.masks["train"]
+no_labels, no_mask = jnp.zeros_like(labels), jnp.zeros_like(mask)
+devs = jax.devices()
+for shape in ((1, 1), (1, 4)):
+    m = make_mesh(shape, ("data", "model"), devices=devs[:shape[1]])
+    st0 = jax.tree.map(lambda a: a.astype(jnp.float64),
+                       SP.init_stack(key, Xp, L, cfg))
+    step, _ = SP.make_distributed_step(m, L, C, cfg)
+    st1, _ = step(st0, Xp, labels, mask)
+    st0, st1 = jax.device_get(st0), jax.device_get(st1)
+    for l in range(L):
+        a = st1.p[l] @ st1.W[l] + st1.b[l]           # = z_old - r, exact
+        if l == L - 1:
+            want = jnp.concatenate([
+                sp.update_z_last_reference(a[:, :C], st0.z[l][:, :C], labels,
+                                           mask, cfg.nu, cfg.fista_iters),
+                sp.update_z_last_reference(a[:, C:], st0.z[l][:, C:],
+                                           no_labels, no_mask, cfg.nu,
+                                           cfg.fista_iters)], axis=1)
+        else:
+            want = sp._zupdate(a, st0.q[l], st0.z[l], cfg.nu, False)
+        gap = np.linalg.norm(st1.z[l] - want) / np.linalg.norm(want)
+        assert gap < 1e-10, (shape, l, gap)
+print("ZLAST_ONE_LAYER_OK")
+""")
+    assert "ZLAST_ONE_LAYER_OK" in out
+
+
 def test_quantized_wire_reduces_ppermute_bytes():
     """HLO proof of the paper's claim: int8 wire shrinks collective-permute
     payloads 4x vs fp32."""

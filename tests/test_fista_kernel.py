@@ -26,7 +26,7 @@ from repro.core import pdadmm, subproblems as sp
 from repro.core.pdadmm import ADMMConfig
 from repro.graph.datasets import synthetic
 from repro.kernels import ops
-from repro.kernels.fista_zlast import momentum_schedule
+from repro.kernels.fista_zlast import momentum_schedule, prox_carry_weight
 
 
 @pytest.fixture
@@ -114,6 +114,48 @@ def test_fista_zlast_head_folded_columns(x64):
     prox = sp.fista_ce(a, z0, labels, jnp.zeros_like(m), 0.5, 9)
     np.testing.assert_allclose(np.asarray(got[:, C:]), np.asarray(prox[:, C:]),
                                atol=1e-10)
+
+
+@pytest.mark.parametrize("path", ["kernel", "jnp"])
+@pytest.mark.parametrize("V,C,width,n_iters,nu", [
+    (300, 1, 1, 15, 1e-2),        # C == width: the unsplit path
+    (300, 1, 200, 15, 1e-2),
+    (513, 3, 1000, 15, 1e-2),     # the head-folded pubmed layer's shape
+    (513, 3, 3, 1, 1.0),
+    (300, 3, 200, 0, 1e-3),
+    (513, 47, 200, 1, 1e-3),
+    (300, 47, 1000, 15, 1.0),
+    (513, 128, 128, 15, 1e-3),
+    (300, 128, 200, 15, 1e-2),
+    (513, 130, 1000, 0, 1e-2),
+    (300, 130, 200, 15, 1.0),
+    (513, 130, 130, 1, 1e-2),
+])
+def test_update_z_last_column_split(x64, monkeypatch, path, V, C, width,
+                                    n_iters, nu):
+    """`update_z_last(..., n_classes=C)` runs FISTA on the C logit columns
+    and writes the rest as α·z_old + (1 − α)·a: the same result as the
+    full-width solve, on the kernel and the jnp path. α is the weight of
+    z_old after iterating the scalar prox-only FISTA recurrence."""
+    a, z0, labels, m = _problem(V, width, seed=V + 7 * C + width)
+    labels = jnp.minimum(labels, C - 1)
+    full = ops.fista_zlast(a, z0, labels, m, nu=nu, n_iters=n_iters,
+                           n_classes=C, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(full),
+        np.asarray(ops.fista_zlast(a, z0, labels, m, nu=nu, n_iters=n_iters,
+                                   n_classes=C, use_pallas=False)),
+        atol=1e-10)
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    got = sp.update_z_last(a, z0, labels, m, nu, n_iters, n_classes=C,
+                           use_kernels=path == "kernel")
+    assert got.shape == full.shape and got.dtype == full.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(full), atol=1e-10)
+
+    scalar = sp.fista_prox(lambda z: nu * z, jnp.ones((), jnp.float64),
+                           1.0 / (1.0 + nu), n_iters)   # a = 0, z_old = 1
+    assert prox_carry_weight(n_iters, nu) == pytest.approx(float(scalar),
+                                                           abs=1e-14)
 
 
 def test_block_admm_ce_path_matches_generic_risk(x64):
