@@ -8,20 +8,21 @@ Readings, each a relative gap against the reference:
   sees;
 * ``risk``: |a - b| / |b| of the loss, the summed cross-entropy of those
   logits;
-* one per state leaf (``p``, ``W``, ``b``, ``z``, ``q``, ``u``):
-  ||a - b|| / ||b|| over the whole leaf, all layers;
+* one per state leaf (``p``, ``W``, ``b``, ``z``, ``q``, ``u``, as the
+  model module names them): ||a - b|| / ||b|| over the whole leaf, all
+  layers that have it;
 * ``z_tail``: the same over the last ``V mod 256`` rows of every layer's z,
   the masked edge block of the z-update kernel's 256-row tile, which a
   whole-leaf norm would dilute;
 * ``residual``: the largest |a_k - b_k| over the chunk's iterations k of
   the primal residual sqrt(sum_l ||p_{l+1} - q_l||^2), over the largest
   b_k;
-* ``move``: how far the chunk moved each state leaf (p, W, b, z, q, u,
-  each over all layers, as the program's state holds it) from its own
-  start, the program's from the program's start and the reference's from
-  the reference's: the worst leaf's gap between the two norms of the
-  change, | ||a - a0|| - ||b - b0|| |, over the reference's norm of that
-  change or of the median leaf's, whichever is larger. A leaf that the
+* ``move``: how far the chunk moved each state leaf (over all layers
+  that have it) from its own start, the program's from the program's start
+  and the reference's from the reference's: the worst leaf's gap between
+  the two norms of the change, | ||a - a0|| - ||b - b0|| |, over the
+  reference's norm of that change or of the median leaf's, whichever is
+  larger. A leaf that the
   reference moves by less than ``STILL`` of the median leaf's change is
   left out. ``move.<leaf>`` is the same for one leaf.
 
@@ -29,20 +30,24 @@ The state gaps compare where the two iterates end; ``move`` compares how
 far each went, so a start that rounds differently does not hide a state
 that never moved.
 
+Both sides come as the model module's ``leaves`` and ``reference_leaves``
+give them: one mapping of leaf name to array per layer, on the host or the
+device. A layer compares the leaves its reference mapping holds.
+
 A cell's limits file names the readings that decide ``correct``; the others
 are printed for the record (PERF.md says why each is or is not compared).
 """
 from __future__ import annotations
 
 import math
-from types import SimpleNamespace
-from typing import Dict, List, Sequence, Tuple
+from collections import defaultdict
+from typing import Dict, List, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-LEAVES = ("p", "W", "b", "z", "q", "u")
+Layers = Sequence[Mapping[str, object]]
 EDGE_TILE = 256
 STILL = 1e-3
 
@@ -51,25 +56,18 @@ def tail_rows(n_nodes: int) -> int:
     return n_nodes % EDGE_TILE or EDGE_TILE
 
 
-def stacked(layers: Sequence) -> SimpleNamespace:
-    """A list of per-layer leaves as leaves indexed by layer."""
-    return SimpleNamespace(**{k: [getattr(l, k) for l in layers]
-                              for k in LEAVES})
-
-
-def gaps(program, reference: Sequence, program_res: Sequence[float],
+def gaps(program: Layers, reference: Layers, program_res: Sequence[float],
          reference_res: Sequence[float], labels, train_mask,
-         n_classes: int, program_start=None, reference_start=None
-         ) -> Dict[str, float]:
-    """``program`` and ``program_start`` hold each leaf indexed by layer (an
-    [L, ...] array or a list, by attribute); ``reference`` and
-    ``reference_start`` are lists of per-layer leaves, on the host or the
-    device. ``move`` is read when both starts are given. The sums run on the
-    device in float32, one layer at a time."""
+         n_classes: int, program_start: Layers = None,
+         reference_start: Layers = None) -> Dict[str, float]:
+    """Each side's leaves layer by layer (see the module's docstring).
+    ``move`` is read when both starts are given. The sums run on the device
+    in float32, one layer at a time."""
     train = np.flatnonzero(np.asarray(train_mask))
     lab = jnp.asarray(np.asarray(labels)[train])
-    a = jnp.asarray(program.z[-1], jnp.float32)[train, :n_classes]
-    b = jnp.asarray(reference[-1].z)[train, :n_classes].astype(jnp.float32)
+    a = jnp.asarray(program[-1]["z"], jnp.float32)[train, :n_classes]
+    b = jnp.asarray(reference[-1]["z"])[train, :n_classes].astype(
+        jnp.float32)
     num, den = _sq_sums(a, b)
     risk_a, risk_b = _risk(a, lab), _risk(b, lab)
     out = {"logits": math.sqrt(float(num) / float(den)),
@@ -78,11 +76,11 @@ def gaps(program, reference: Sequence, program_res: Sequence[float],
     b_res = np.asarray(reference_res, np.float64)
     out["residual"] = float(np.max(np.abs(a_res - b_res))
                             / np.max(np.abs(b_res)))
-    sums = {k: [0.0, 0.0] for k in LEAVES + ("z_tail",)}
-    for l, ref_layer in enumerate(reference):
-        for k in LEAVES:
-            b = jnp.asarray(getattr(ref_layer, k))
-            a = jnp.asarray(getattr(program, k)[l], jnp.float32)
+    sums = defaultdict(lambda: [0.0, 0.0])
+    for prog_layer, ref_layer in zip(program, reference, strict=True):
+        for k, b in ref_layer.items():
+            b = jnp.asarray(b)
+            a = jnp.asarray(prog_layer[k], jnp.float32)
             num, den = _sq_sums(a, b)
             sums[k][0] += float(num)
             sums[k][1] += float(den)
@@ -98,25 +96,25 @@ def gaps(program, reference: Sequence, program_res: Sequence[float],
     return out
 
 
-def change_norms(program, program_start, reference: Sequence,
-                 reference_start: Sequence) -> List[Tuple[str, int, float,
-                                                          float]]:
-    """(leaf, layer, ||a - a0||, ||b - b0||) for every leaf of every layer."""
+def change_norms(program: Layers, program_start: Layers, reference: Layers,
+                 reference_start: Layers) -> List[Tuple[str, int, float,
+                                                        float]]:
+    """(leaf, layer, ||a - a0||, ||b - b0||) for every leaf of every layer
+    that the reference holds."""
     norms = []
-    for l, (ref, ref0) in enumerate(zip(reference, reference_start)):
-        for k in LEAVES:
-            da, db = _change(jnp.asarray(getattr(program, k)[l]),
-                             jnp.asarray(getattr(program_start, k)[l]),
-                             jnp.asarray(getattr(ref, k)),
-                             jnp.asarray(getattr(ref0, k)))
+    for l, (a, a0, ref, ref0) in enumerate(zip(
+            program, program_start, reference, reference_start, strict=True)):
+        for k in ref:
+            da, db = _change(jnp.asarray(a[k]), jnp.asarray(a0[k]),
+                             jnp.asarray(ref[k]), jnp.asarray(ref0[k]))
             norms.append((k, l, float(da), float(db)))
     return norms
 
 
-def moves(program, program_start, reference: Sequence,
-          reference_start: Sequence) -> Dict[str, float]:
+def moves(program: Layers, program_start: Layers, reference: Layers,
+          reference_start: Layers) -> Dict[str, float]:
     """``move`` and ``move.<leaf>`` (see the module's docstring)."""
-    sq = {k: [0.0, 0.0] for k in LEAVES}
+    sq = defaultdict(lambda: [0.0, 0.0])
     for k, _, da, db in change_norms(program, program_start, reference,
                                      reference_start):
         sq[k][0] += da * da

@@ -10,10 +10,11 @@ For each seed, in one process and without a measured window:
 * ``control``: the reference computed in bfloat16, put in the program's
   place: the upper readings;
 * with ``--faults``, the reference put in the program's place with a fault
-  planted: ``unchanged`` (the state never moves), ``half_batch`` (only the
-  first half of the nodes is updated; b's mean is over that half),
-  ``altered`` (the last layer's first logit of every node is raised by 1
-  where it is produced).
+  planted: ``unchanged`` (the state never moves), and each fault of the
+  model module's ``faults`` (for ``gamlp_projected``: ``half_batch``, only
+  the first half of the nodes updated, b's mean over that half; ``altered``,
+  the last layer's first logit of every node raised by 1 where it is
+  produced).
 
 One JSON line per seed goes to standard output: the readings, and under
 ``changes`` each reading's per-leaf norms of the change from its start
@@ -35,34 +36,12 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import run  # noqa: E402
-from compare import change_norms, gaps, stacked  # noqa: E402
+from compare import change_norms, gaps  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
-def half_batch(ref):
-    def iterate(layers, labels, mask, prm):
-        h = layers[0].p.shape[0] // 2
-        top = [ref.Layer(l.p[:h], l.W, l.b, l.z[:h], l.q[:h], l.u[:h])
-               for l in layers]
-        new, res = ref.iterate(top, labels[:h], mask[:h], prm)
-        del top
-        join = lambda a, b: jnp.concatenate([a, b[h:]])
-        return [ref.Layer(join(n.p, o.p), n.W, n.b, join(n.z, o.z),
-                          join(n.q, o.q), join(n.u, o.u))
-                for n, o in zip(new, layers)], res
-    return iterate
-
-
-def altered(ref):
-    def iterate(layers, labels, mask, prm):
-        new, res = ref.iterate(layers, labels, mask, prm)
-        last = new[-1]
-        new[-1] = last._replace(z=last.z.at[:, 0].add(1.0))
-        return new, res
-    return iterate
-
-
 def readings(cell, seed: int, devices, faults: bool, log=print) -> dict:
+    model = cell.model
     prog = run.Program(cell, seed, devices, log)
     _, res, checked = run.warm_up(prog)
     prog.state = None
@@ -70,38 +49,37 @@ def readings(cell, seed: int, devices, faults: bool, log=print) -> dict:
     del prog
     gc.collect()
     t0 = time.perf_counter()
-    ref_start, ref_layers, ref_res = run.reference_run(cell, seed, inputs)
+    ref_start, ref_end, ref_res = run.reference_run(cell, seed, inputs,
+                                                    devices)
     log(f"seed {seed}: reference {time.perf_counter() - t0:.3f} s")
     # the readings below each hold a whole state on the device; keep the
     # reference on the host meanwhile
-    ref_start, ref_layers = jax.device_get((ref_start, ref_layers))
+    ref_start, ref_end = jax.device_get((model.reference_leaves(ref_start),
+                                         model.reference_leaves(ref_end)))
     against = (inputs.labels, inputs.train_mask, cell.config["classes"])
     out = {"seed": seed, "changes": {}}
 
     def record(name, end, end_res, begin):
-        out[name] = gaps(end, ref_layers, end_res, ref_res, *against,
+        out[name] = gaps(end, ref_end, end_res, ref_res, *against,
                          begin, ref_start)
-        out["changes"][name] = change_norms(end, begin, ref_layers,
-                                            ref_start)
+        out["changes"][name] = change_norms(end, begin, ref_end, ref_start)
 
-    record("program", checked, res, start)
+    record("program", model.leaves(checked), res, model.leaves(start))
     del checked, start
     t0 = time.perf_counter()
-    low0, low, low_res = run.reference_run(cell, seed, inputs,
+    low0, low, low_res = run.reference_run(cell, seed, inputs, devices,
                                            dtype=jnp.bfloat16)
     log(f"seed {seed}: control {time.perf_counter() - t0:.3f} s")
-    record("control", stacked(low), low_res, stacked(low0))
+    record("control", model.reference_leaves(low), low_res,
+           model.reference_leaves(low0))
     del low0, low
     if faults:
-        still = stacked(ref_start)
-        record("unchanged", still, [0.0] * len(ref_res), still)
-        planted = {"half_batch": half_batch(cell.ref),
-                   "altered": altered(cell.ref)}
-        for name, iterate in planted.items():
-            _, layers, fres = run.reference_run(cell, seed, inputs,
-                                                iterate=iterate)
-            record(name, stacked(layers), fres, still)
-            del layers
+        record("unchanged", ref_start, [0.0] * len(ref_res), ref_start)
+        for name, iterate in model.faults(cell).items():
+            _, end, fres = run.reference_run(cell, seed, inputs, devices,
+                                             iterate=iterate)
+            record(name, model.reference_leaves(end), fres, ref_start)
+            del end
     return out
 
 

@@ -53,6 +53,11 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
+def state_key(seed: int) -> jax.Array:
+    """The key of a model's start state (weights), apart from its inputs'."""
+    return jax.random.fold_in(seed_key(seed), 1)
+
+
 def make_graph(key, labels, n_nodes: int, n_edges: int, n_classes: int
                ) -> Graph:
     V, E, C = n_nodes, n_edges, n_classes

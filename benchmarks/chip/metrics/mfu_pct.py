@@ -1,13 +1,11 @@
-"""The step's share of the chips' bf16 peak, in percent: the update
-family's contractions (``counts.contraction_flops``) over the traced window,
-divided by chips x peak."""
-from counts import contraction_flops
+"""The step's share of the chips' bf16 peak, in percent: the contraction
+FLOPs of one iteration, as the configuration's model module counts them
+(``contraction_flops``), times the iterations of the traced window, over
+the window's length times chips x peak."""
 
 
 def read(ctx):
-    cfg = ctx.config
-    flops = contraction_flops(cfg["nodes"], cfg["hidden"], cfg["layers"]) \
-        * ctx.iterations
+    flops = ctx.model.contraction_flops(ctx.config) * ctx.iterations
     seconds = ctx.trace.window_s
     if not ctx.trace.devices or seconds <= 0:
         return None

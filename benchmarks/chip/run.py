@@ -5,21 +5,38 @@
 
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a model
 configuration (``configs/<config>.json``) and a job (``traffic/<mix>.json``).
-The run builds the cell's inputs on the device from ``--seed``, builds the
-program's no-controller training path as ``distributed_train`` runs it
-(``init_stack``, state and data sharded by ``stack_partition_specs`` and
-``P("data")``, ``make_distributed_step(overlap=False, health=False)``),
-and drives it by successive ``run_chunked`` calls of one chunk each:
+The configuration names its model module, ``models/<config["model"]>.py``,
+and its plain reference, ``reference/<config["reference"]>.py``. The
+harness reaches everything that is particular to a model through that
+module:
+
+* ``inputs(config, seed)``: the cell's data, made on the device from the
+  seed, with ``labels`` and ``train_mask`` among its fields;
+* ``program(cell, devices, data)``: the program's step on its normal
+  path, its arguments placed as the program places them, and
+  ``start(seed)``, the seed's start state made by the program's own
+  initialiser;
+* ``reference(cell, seed, data, devices, dtype, iterate)``: the
+  reference's start from the same data and its step,
+  ``step(state) -> (state, primal residual)``, computed in ``dtype``;
+  ``iterate`` replaces the reference's iteration (``control.py``'s faults);
+* ``leaves(state)``, ``reference_leaves(state)``: the leaves the comparison
+  reads, one mapping of leaf name to array per layer (a leaf that a layer
+  does not have, or that is a fixed input, is left out of that layer's);
+* ``faults(cell)``: the reference's iteration with faults planted, by name;
+* ``contraction_flops(config)``: the contraction FLOPs of one iteration.
+
+The run drives the step by successive ``run_chunked`` calls of one chunk
+each:
 
 * set-up: inputs, state, and one warm-up chunk, whose state is kept on
   the host for the check;
 * window: chunk after chunk, carrying the state, until ``--seconds`` have
   passed;
 * check: once the window has closed and the program's state is freed, the
-  program's start state is made again by its own ``init_stack`` from the
-  seed, the plain reference (``reference/<name>.py``) repeats the warm-up
-  chunk from the same seed, and ``compare.py`` holds every number to the
-  cell's limit in ``limits/<cell>.json``.
+  program's start state is made again from the seed, the reference repeats
+  the warm-up chunk from the same seed, and ``compare.py`` holds every
+  number to the cell's limit in ``limits/<cell>.json``.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` traces
 the window and reports its per-layer metrics, each read by
@@ -55,18 +72,12 @@ for p in (HERE, ROOT / "src"):
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 import trace_reduce as tr  # noqa: E402
 from compare import gaps, judge  # noqa: E402
 from counts import pallas_calls, peaks as peaks_of  # noqa: E402
-from inputs import inputs_for, seed_key  # noqa: E402
 from repro.core import pdadmm  # noqa: E402
-from repro.core.pdadmm import ADMMConfig  # noqa: E402
-from repro.core.quantize import QuantGrid  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.parallel import stage_parallel as SP  # noqa: E402
 
 SPAN = "bench.chunk"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -86,8 +97,9 @@ def load_module(path: Path):
 
 class Cell:
     """Everything a run needs to know about one cell, found by name: the
-    manifest entry, the configuration, the job, the limits of the check and
-    the readers of its per-layer metrics."""
+    manifest entry, the configuration, its model module and reference, the
+    job, the limits of the check and the readers of its per-layer
+    metrics."""
 
     def __init__(self, name: str, root: Path = ROOT):
         manifest = load_json(root / "BENCHMARK.json")
@@ -110,6 +122,11 @@ class Cell:
                           if name in m.get("workloads", [name])}
 
     @functools.cached_property
+    def model(self):
+        """The configuration's model module (loaded once)."""
+        return load_module(self.dir / "models" / f"{self.config['model']}.py")
+
+    @functools.cached_property
     def ref(self):
         """The configuration's plain reference module (loaded once, so its
         jitted functions compile once per process)."""
@@ -120,63 +137,27 @@ class Cell:
         return load_module(self.dir / "metrics" / f"{metric}.py")
 
 
-def admm_config(traffic: dict):
-    g = traffic["grid"]
-    grid = None if g is None else QuantGrid(float(g["lo"]), float(g["step"]),
-                                            int(g["levels"]))
-    return ADMMConfig(nu=traffic["nu"], rho=traffic["rho"],
-                      tau0=traffic["tau0"], fista_iters=traffic["fista_iters"],
-                      quantize_p=traffic["quantize_p"],
-                      quantize_q=traffic["quantize_q"], grid=grid)
-
-
-def reference_params(ref, traffic: dict, n_classes: int):
-    g = traffic["grid"]
-    grid = None if g is None else ref.Grid(float(g["lo"]), float(g["step"]),
-                                           int(g["levels"]))
-    return ref.Params(nu=traffic["nu"], rho=traffic["rho"],
-                      tau0=traffic["tau0"], fista_iters=traffic["fista_iters"],
-                      n_classes=n_classes,
-                      p_grid=grid if traffic["quantize_p"] else None,
-                      q_grid=grid if traffic["quantize_q"] else None)
-
-
-def state_key(seed: int):
-    return jax.random.fold_in(seed_key(seed), 1)
-
-
 class Program:
     """The system under test, as the window drives it."""
 
     def __init__(self, cell: Cell, seed: int, devices, log):
-        cfg, self.cell = cell.config, cell
-        self.L, self.C = int(cfg["layers"]), int(cfg["classes"])
         self.chunk = int(cell.traffic["chunk"])
-        self.admm = admm_config(cell.traffic)
-        mesh_shape = tuple(cfg["mesh"])
-        self.mesh = make_mesh(mesh_shape, ("data", "model"),
-                              devices=devices[:math.prod(mesh_shape)])
         t0 = time.perf_counter()
-        self.inputs = jax.block_until_ready(inputs_for(cfg, seed))
-        log(f"inputs: V={cfg['nodes']} d={cfg['features']} K={cfg['k_hops']} "
-            f"h={cfg['hidden']} L={self.L} C={self.C} mesh={mesh_shape}; "
-            f"{time.perf_counter() - t0:.3f} s")
-        self.step, specs = SP.make_distributed_step(
-            self.mesh, self.L, self.C, self.admm, overlap=False, health=False)
-        shard = lambda spec: NamedSharding(self.mesh, spec)
-        put = lambda x: jax.device_put(x, shard(P("data")))
-        self.args = tuple(put(x) for x in self.inputs)
+        self.inputs = jax.block_until_ready(
+            cell.model.inputs(cell.config, seed))
+        shapes = [tuple(x.shape) for x in jax.tree.leaves(self.inputs)]
+        log(f"inputs: {cell.config['name']} ({cell.config['model']}) "
+            f"{shapes}; {time.perf_counter() - t0:.3f} s")
+        self.step, self.args, self._start = cell.model.program(
+            cell, devices, self.inputs)
         self.seed = seed
-        self._init = jax.jit(SP.init_stack, static_argnums=(2, 3),
-                             out_shardings=jax.tree.map(shard, specs))
         t0 = time.perf_counter()
         self.state = self.start_state()
-        log(f"init_stack: {time.perf_counter() - t0:.3f} s")
+        log(f"start state: {time.perf_counter() - t0:.3f} s")
 
     def start_state(self):
-        """The seed's start state, as ``init_stack`` makes it."""
-        return jax.block_until_ready(self._init(
-            state_key(self.seed), self.args[0], self.L, self.admm))
+        """The seed's start state, as the program makes it."""
+        return jax.block_until_ready(self._start(self.seed))
 
     def compile_chunk(self):
         """The chunk program, from the cache -> (bytes it needs on the
@@ -209,41 +190,42 @@ def warm_up(prog: Program):
     return ms, [float(x) for x in ms["residual"]], jax.device_get(prog.state)
 
 
-def reference_run(cell: Cell, seed: int, inputs, dtype=None, iterate=None):
-    """The reference over one chunk from the seed -> (start layers, end
-    layers, residuals). ``iterate`` replaces the reference's iteration
+def reference_run(cell: Cell, seed: int, inputs, devices,
+                  dtype=jnp.float32, iterate=None):
+    """The reference over one chunk from the seed -> (start state, end
+    state, residuals). ``iterate`` replaces the reference's iteration
     (faults)."""
-    ref = cell.ref
-    prm = reference_params(ref, cell.traffic, int(cell.config["classes"]))
-    start = ref.init(state_key(seed), inputs.Xp, int(cell.config["layers"]),
-                     prm, dtype or jnp.float32)
-    layers, step, res = start, iterate or ref.iterate, []
+    start, step = cell.model.reference(cell, seed, inputs, devices, dtype,
+                                       iterate)
+    state, res = start, []
     for _ in range(int(cell.traffic["chunk"])):
-        layers, r = step(layers, inputs.labels, inputs.train_mask, prm)
+        state, r = step(state)
         res.append(float(r))
-    return start, layers, res
+    return start, state, res
 
 
-def reference_check(cell: Cell, seed: int, inputs, checked, program_start,
-                    program_res):
+def reference_check(cell: Cell, seed: int, inputs, devices, checked,
+                    program_start, program_res):
     """Run the reference over the warm-up chunk; the gaps of the program."""
-    start, layers, res = reference_run(cell, seed, inputs)
-    return gaps(checked, layers, program_res, res, inputs.labels,
-                inputs.train_mask, int(cell.config["classes"]),
-                program_start, start)
+    start, end, res = reference_run(cell, seed, inputs, devices)
+    model = cell.model
+    return gaps(model.leaves(checked), model.reference_leaves(end),
+                program_res, res, inputs.labels, inputs.train_mask,
+                int(cell.config["classes"]), model.leaves(program_start),
+                model.reference_leaves(start))
 
 
-def read_trace(cell: Cell, log_dir: str, kernels: Dict[str, tuple],
-               program_bytes: int, iterations: int, peaks: dict,
-               n_devices: int):
+def read_trace(cell: Cell, log_dir: str, hlo: str,
+               kernels: Dict[str, tuple], program_bytes: int,
+               iterations: int, peaks: dict, n_devices: int):
     t = tr.load(log_dir, SPAN)
     used = {d: ops for d, ops in sorted(t.devices.items())
             if d < n_devices and ops}
     t = tr.Trace(used, t.spans, t.window)
     ctx = collections.namedtuple(
-        "Context", "trace kernels program_bytes iterations peaks config "
-        "chips")(t, kernels, program_bytes, iterations, peaks, cell.config,
-                 cell.chips)
+        "Context", "trace hlo kernels program_bytes iterations peaks config "
+        "chips model")(t, hlo, kernels, program_bytes, iterations, peaks,
+                       cell.config, cell.chips, cell.model)
     values = {}
     for name, m in cell.per_layer.items():
         v = cell.metric_reader(name).read(ctx)
@@ -325,7 +307,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             f"device; {len(kernels)} Pallas calls "
             f"{sorted(per_kernel.items())}")
         values, breakdown, busy_s, window_s = read_trace(
-            cell, tmp.name, kernels, need, iters, peaks, cell.chips)
+            cell, tmp.name, hlo, kernels, need, iters, peaks, cell.chips)
         tmp.cleanup()
         result["metrics"] = values
         result["breakdown"] = breakdown
@@ -341,10 +323,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     t0 = time.perf_counter()
     prog.state = ms = None
     gc.collect()
-    start, inputs = prog.start_state(), prog.inputs
+    start, inputs = jax.device_get(prog.start_state()), prog.inputs
     del prog
     gc.collect()
-    values = reference_check(cell, seed, inputs, checked, start, warm_res)
+    values = reference_check(cell, seed, inputs, used, checked, start,
+                             warm_res)
     ok, lines = judge(values, cell.limits)
     log("readings not compared: " + ", ".join(
         f"{k} {v:.6e}" for k, v in values.items() if k not in cell.limits))

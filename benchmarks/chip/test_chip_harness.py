@@ -23,6 +23,8 @@ import run  # noqa: E402
 import trace_reduce as tr  # noqa: E402
 
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
+MODEL_API = ("inputs", "program", "reference", "leaves", "reference_leaves",
+             "faults", "contraction_flops")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 
@@ -93,8 +95,9 @@ def test_every_cell_finds_its_files_by_name(workload):
     assert cell.per_layer
     for metric in cell.per_layer:
         assert callable(cell.metric_reader(metric).read)
-    ref = cell.ref
-    assert callable(ref.iterate) and callable(ref.init)
+    for name in MODEL_API:
+        assert callable(getattr(cell.model, name)), name
+    assert cell.ref is not None
 
 
 def test_a_new_mix_and_metric_need_no_edit_of_an_existing_file(tmp_path):
@@ -128,6 +131,62 @@ def test_a_new_mix_and_metric_need_no_edit_of_an_existing_file(tmp_path):
         type("Ctx", (), {"trace": trace})) == 2.0
     old = run.Cell("pubmed-L8.fp32", root=tmp_path)
     assert "window_s_probe" not in old.per_layer
+    for p, data in before.items():
+        assert p.read_bytes() == data, p
+
+
+@pytest.mark.parametrize("fault", [None, "unchanged"])
+def test_a_second_model_needs_no_edit_of_an_existing_file(
+        tmp_path, monkeypatch, fault):
+    """A GA-MLP whose first layer is K·d -> h on the unprojected [V, K·d]
+    augmentation, on the serial program, whose state has no q or u in its
+    last layer: added as a model module, a reference, a configuration,
+    limits and manifest entries, it runs through ``run.run_cell`` to
+    ``correct``; with its step returning the state unchanged, not."""
+    import jax
+
+    from repro.core import pdadmm
+    shutil.copytree(HERE, tmp_path / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes()
+              for p in (tmp_path / "benchmarks").rglob("*") if p.is_file()}
+    d = tmp_path / "benchmarks" / "chip"
+    added = HERE / "test_second_model"
+    for src in added.rglob("*"):
+        if src.is_dir() or "__pycache__" in src.parts:
+            continue
+        dst = d / src.relative_to(added)
+        assert not dst.exists(), dst
+        shutil.copy(src, dst)
+    manifest = json.loads(json.dumps(MANIFEST))
+    manifest["configs"].append(
+        {"name": "gamlp_serial-L3",
+         "source": "https://arxiv.org/abs/2105.09837",
+         "file": "benchmarks/chip/configs/gamlp_serial-L3.json",
+         "reduced": [], "why": "a test configuration"})
+    manifest["workloads"].append(
+        {"name": "gamlp_serial-L3.fp32", "config": "gamlp_serial-L3",
+         "traffic": "fp32", "chips": 1, "why": "a test cell"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    if fault == "unchanged":
+        step = pdadmm.iterate
+        monkeypatch.setattr(pdadmm, "iterate",
+                            lambda s, *a, **k: (s, step(s, *a, **k)[1]))
+
+    cell = run.Cell("gamlp_serial-L3.fp32", root=tmp_path)
+    K_d = cell.config["k_hops"] * cell.config["features"]
+    result = run.run_cell(cell, 2 ** 33 + 29, 0.01, False,
+                          jax.devices()[:1], log=lambda s: None)
+    assert result["correct"] is (fault is None), result["compared"]
+    assert set(result["compared"]) == set(cell.limits)
+    data = cell.model.inputs(cell.config, 3)
+    assert data.Psi.shape == (cell.config["nodes"], K_d)
+    layers = cell.model.leaves(cell.model.program(
+        cell, jax.devices()[:1], data)[2](3))
+    assert layers[0]["W"].shape == (K_d, cell.config["hidden"])
+    assert "p" not in layers[0] and "q" not in layers[-1]
+    assert cell.model.contraction_flops(cell.config) == 2 * 300 * (
+        3 * 160 * 128 + 5 * 128 * 128 + 5 * 128 * 3)
     for p, data in before.items():
         assert p.read_bytes() == data, p
 
@@ -205,44 +264,39 @@ def test_per_hop_projection_equals_augment_then_project():
 
 # -- the comparison's arithmetic ---------------------------------------------
 
-def _layers(rng, n_layers, scale=1.0):
-    from types import SimpleNamespace
+LEAVES = ("p", "W", "b", "z", "q", "u")
 
-    import compare
-    return [SimpleNamespace(**{k: scale * rng.standard_normal(
+
+def _layers(rng, n_layers, scale=1.0):
+    return [{k: scale * rng.standard_normal(
         (4,) if k == "b" else (6, 4)).astype(np.float32)
-        for k in compare.LEAVES}) for _ in range(n_layers)]
+        for k in LEAVES} for _ in range(n_layers)]
 
 
 @pytest.mark.parametrize("case,want", [
     ("exact", 0.0), ("frozen", 1.0), ("one_leaf_half_again", 0.5),
     ("still_leaf_moved", 0.0)])
 def test_move_is_the_worst_leafs_gap_of_change_norms(case, want):
-    from types import SimpleNamespace
-
     import compare
     rng = np.random.default_rng(0)
     start = _layers(rng, 3)
     step = _layers(rng, 3)
-    for k in compare.LEAVES:      # every leaf's change has norm 1 ...
-        total = np.sqrt(sum(np.sum(getattr(d, k) ** 2) for d in step))
+    for k in LEAVES:              # every leaf's change has norm 1 ...
+        total = np.sqrt(sum(np.sum(d[k] ** 2) for d in step))
         for d in step:
-            setattr(d, k, getattr(d, k) / total)
+            d[k] = d[k] / total
     for d in step:                # ... but q, which the reference holds
-        d.q = np.zeros_like(d.q)
-    end = [SimpleNamespace(**{k: getattr(a, k) + getattr(d, k)
-                              for k in compare.LEAVES})
-           for a, d in zip(start, step)]
-    mine = [SimpleNamespace(**vars(x)) for x in end]
+        d["q"] = np.zeros_like(d["q"])
+    end = [{k: a[k] + d[k] for k in LEAVES} for a, d in zip(start, step)]
+    mine = [dict(x) for x in end]
     if case == "frozen":
         mine = start
     elif case == "one_leaf_half_again":
         for a, d, m in zip(start, step, mine):
-            m.z = a.z + 1.5 * d.z
+            m["z"] = a["z"] + 1.5 * d["z"]
     elif case == "still_leaf_moved":
-        mine[2].q = start[2].q + step[2].p
-    got = compare.moves(compare.stacked(mine), compare.stacked(start), end,
-                        start)
+        mine[2]["q"] = start[2]["q"] + step[2]["p"]
+    got = compare.moves(mine, start, end, start)
     assert got["move"] == pytest.approx(want, abs=1e-6)
     if case == "one_leaf_half_again":
         assert got["move.z"] == pytest.approx(0.5, abs=1e-6)
@@ -298,6 +352,8 @@ def test_contraction_flops_by_hand():
     # 5 contractions x 8 layers x 2 x 19,717 x 1000^2 = 1.57736 TFLOP
     assert counts.contraction_flops(19717, 1000, 8) == 1_577_360_000_000.0
     assert counts.contraction_flops(34493, 1000, 8) == 5 * 8 * 2 * 34493e6
+    cell = run.Cell("pubmed-L8.fp32")
+    assert cell.model.contraction_flops(cell.config) == 1_577_360_000_000.0
 
 
 FISTA_LINE = (
@@ -339,12 +395,13 @@ def test_peaks_are_keyed_by_device_kind():
 
 def _ctx(devices, kernels, iterations=10, chips=1, program_bytes=0):
     import collections
-    cfg = run.Cell("pubmed-L8.fp32").config
+    cell = run.Cell("pubmed-L8.fp32")
     trace = tr.Trace(devices, [], (0.0, 1e9))
     Ctx = collections.namedtuple(
-        "Ctx", "trace kernels program_bytes iterations peaks config chips")
-    return Ctx(trace, kernels, program_bytes, iterations,
-               counts.peaks("TPU v5 lite"), cfg, chips)
+        "Ctx", "trace hlo kernels program_bytes iterations peaks config "
+        "chips model")
+    return Ctx(trace, "", kernels, program_bytes, iterations,
+               counts.peaks("TPU v5 lite"), cell.config, chips, cell.model)
 
 
 def _read(metric, ctx):
