@@ -383,6 +383,124 @@ def test_fista_call_bytes_from_its_padded_hlo_shapes():
     assert calls["_fista_zlast.3"][0] == "admm_pgrad.<locals>.kernel"
 
 
+MiB_BLOCK = 2048 * 128 * 4          # one [2048, 128] f32 array
+PLACED_HLO = """\
+HloModule jit__scan_chunk, is_scheduled=true
+%body.1 (p: (s32[], f32[2048,128])) -> (s32[], f32[2048,128]) {
+  %z_prev.1 = f32[2048,128]{1,0:T(8,128)} get-tuple-element(%p), index=1
+  %copy-done.2 = f32[2048,128]{1,0:T(8,128)S(1)} copy-done(%copy-start.2)
+  %reshape.3 = s32[2048,1]{1,0:T(8,128)S(1)} reshape(%labels.1)
+  %reshape.4 = f32[2048,1]{1,0:T(8,128)} reshape(%mask.1)
+  %_fista_zlast.4 = f32[2048,128]{1,0:T(8,128)} custom-call(%z_prev.1, %z_prev.1, %copy-done.2, %reshape.3, %reshape.4), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[2048,128]{1,0}, f32[2048,128]{1,0}, f32[2048,128]{1,0}, s32[2048,1]{1,0}, f32[2048,1]{1,0}}, backend_config={"custom_call_config":{"body":"BODY"}}
+  ROOT %_fista_zlast.5 = f32[2048,128]{1,0:T(8,128)S(1)} custom-call(%z_prev.1, %_fista_zlast.4, %copy-done.2, %reshape.3, %reshape.4), custom_call_target="tpu_custom_call", operand_layout_constraints={f32[2048,128]{1,0}, f32[2048,128]{1,0}, f32[2048,128]{1,0}, s32[2048,1]{1,0}, f32[2048,1]{1,0}}, backend_config={"custom_call_config":{"body":"BODY"}}
+}
+"""
+
+
+def test_fista_bytes_leave_out_what_the_compiler_placed_in_vmem():
+    """The compiler keeps some FISTA operands and results in VMEM (layout
+    ``S(1)``) from one call to the next; they move no HBM bytes. A [V, 1]
+    column in HBM stores 128 lanes a row (tile ``T(8,128)``). Counting
+    every operand and result at its logical shape reads these two calls
+    at 57% where they run at 50% of HBM bandwidth, and reads a program
+    that keeps more in VMEM above 100%."""
+    import base64
+    body = base64.b64encode(
+        b"\x00src/repro/kernels/fista_zlast.py\x00_fista_step_kernel\x00v\x00"
+    ).decode()
+    calls = counts.pallas_calls(PLACED_HLO.replace("BODY", body))
+    # z_prev twice, the mask column and the result; then z_prev, the first
+    # call's result and the mask column: a, the labels and the second
+    # result lie in VMEM
+    assert calls == {"_fista_zlast.4": ("_fista_step_kernel", 4 * MiB_BLOCK),
+                     "_fista_zlast.5": ("_fista_step_kernel", 3 * MiB_BLOCK)}
+    assert counts.hbm_bytes("f32[2048,1]{1,0:T(8,128)}") == MiB_BLOCK
+    assert counts.hbm_bytes("f32[2048,128]{1,0:T(8,128)S(1)}") == 0
+    assert counts.hbm_bytes("f32[19717,1000]{1,0:T(8,128)}") == (
+        19720 * 1024 * 4)
+    assert counts.hbm_bytes("f32[8,19717,1000]{2,1,0}") == 8 * 19717 * 4000
+
+    at_half = lambda n: 2 * n * MiB_BLOCK / 819e9 * 1e9     # ns at 50%
+    d0 = _ops(("_fista_zlast.4", 0, at_half(4)),
+              ("_fista_zlast.5", at_half(4), at_half(7)))
+    ctx = _ctx({0: d0}, calls)
+    assert _read("roofline_pct.fista_zlast", ctx) == pytest.approx(50.0)
+
+
+def test_fista_bytes_count_a_prefetch_into_vmem_once():
+    """An async copy that brings ``a`` from HBM into VMEM before the solve
+    reads its bytes from HBM once for all the calls that use it: they count
+    for the first of them. With the labels and mask in VMEM and the z
+    iterates kept there in turn, the calls would otherwise move next to
+    nothing, and a solve whose every operand lay in VMEM would read as no
+    share at all."""
+    import base64
+    body = base64.b64encode(
+        b"\x00src/repro/kernels/fista_zlast.py\x00_fista_step_kernel\x00v\x00"
+    ).decode()
+    prefetch = PLACED_HLO.replace(
+        "  %copy-done.2",
+        "  %a.1 = f32[2048,128]{1,0:T(8,128)} get-tuple-element(%p), index=2\n"
+        "  %copy-start.2 = (f32[2048,128]{1,0:T(8,128)S(1)}, "
+        "f32[2048,128]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%a.1)\n"
+        "  %copy-done.2").replace("BODY", body)
+    calls = counts.pallas_calls(prefetch)
+    assert calls == {"_fista_zlast.4": ("_fista_step_kernel", 5 * MiB_BLOCK),
+                     "_fista_zlast.5": ("_fista_step_kernel", 3 * MiB_BLOCK)}
+    # a copy whose source already lies in VMEM reads nothing from HBM
+    in_vmem = prefetch.replace("f32[2048,128]{1,0:T(8,128)}, u32[]",
+                               "f32[2048,128]{1,0:T(8,128)S(1)}, u32[]")
+    assert counts.pallas_calls(in_vmem)["_fista_zlast.4"][1] == 4 * MiB_BLOCK
+
+
+@pytest.mark.parametrize("config", ["pubmed-L8", "coauthor_physics"])
+def test_fista_call_bytes_are_what_its_grid_and_blocks_move(config,
+                                                            monkeypatch):
+    """One ``fista_step`` call at the padded rows of pubmed (19,717 ->
+    19,968) and of coauthor_physics (34,493 -> 34,560), lowered for the
+    TPU: ``pallas_calls`` counts what the kernel's grid and BlockSpecs move,
+    V // bm steps of three [bm, N] reads, two [bm, 1] reads and one [bm, N]
+    write. Lowered, nothing is yet placed in VMEM or tiled; in the compiled
+    program each [bm, 1] block of a column that lies in HBM is stored, and
+    moved, as [bm, 128] (tile T(8,128)), and a block in VMEM moves no HBM
+    bytes, so the count of a compiled call differs from this one by those
+    two terms alone."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import fista_zlast, ops
+    nodes = {"pubmed-L8": 19717, "coauthor_physics": 34493}[config]
+    V, N = ops.padded_shape("fista_zlast", (nodes, 5))
+    seen = []
+    pallas_call = pl.pallas_call
+
+    def record(kernel, **kw):
+        seen.append(kw)
+        return pallas_call(kernel, **kw)
+    monkeypatch.setattr(pl, "pallas_call", record)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    step = lambda zp, zc, a, lab, m: fista_zlast.fista_step(
+        zp, zc, a, lab, m, mom=0.5, step=0.99, nu=0.01, n_classes=5)
+    text = jax.jit(step).trace(
+        f32(V, N), f32(V, N), f32(V, N),
+        jax.ShapeDtypeStruct((V, 1), jnp.int32), f32(V, 1)).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    (spec,) = seen
+    (steps,) = spec["grid"]
+    block = lambda b: 4 * b.block_shape[0] * b.block_shape[1]
+    moved = steps * (sum(block(b) for b in spec["in_specs"])
+                     + block(spec["out_specs"]))
+    bm = spec["out_specs"].block_shape[0]
+    assert (steps, N) == (V // bm, 128)
+    assert moved == V // bm * (3 * bm * N + 2 * bm + bm * N) * 4
+    ((_, counted),) = counts.pallas_calls(text).values()
+    assert counted == moved
+    # the two columns as a compiled program lays them out in HBM
+    tiled = counts.hbm_bytes(f"s32[{V},1]{{1,0:T(8,128)}}")
+    assert tiled == steps * bm * 128 * 4
+
+
 def test_peaks_are_keyed_by_device_kind():
     v5e = counts.peaks("TPU v5 lite")
     assert v5e["bf16_flops_per_s"] == 197e12
